@@ -355,6 +355,9 @@ def main(argv=None):
                     help="smaller matrix for CI")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     here = pathlib.Path(__file__).parent
     if args.out is None:

@@ -27,11 +27,12 @@ Writes BENCH_mesh.json next to this file (``--dry-run`` →
 BENCH_mesh.dryrun.json for the CI smoke; rows are deterministic, so
 ``perf_smoke.py`` replays them exactly).  Exit status 1 when the headline
 claim fails: at skew >= 4 mesh-ws must beat the static makespan, and every
-row must be **bit-identical** to the no-drop oracle (max_abs_err == 0).
+row must match the no-drop oracle to float32 accumulation order
+(``repro.mesh_ws.selfcheck.ORACLE_RTOL``/``ORACLE_ATOL``).
 
-Needs D forced host devices; re-execs itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=D`` when the live
-process has fewer (the count locks at first jax init).
+Needs D devices.  On a CPU backend (``JAX_PLATFORMS=cpu``) it re-execs
+itself with ``XLA_FLAGS=--xla_force_host_platform_device_count=D``, decided
+before it touches JAX; elsewhere it runs on the process's own devices.
 """
 
 from __future__ import annotations
@@ -58,6 +59,7 @@ def run_one(T, d, f, E, D, k, P, bt, skew, seed=0, trace_sink=None):
         expert_ffn_mesh_ws,
         mesh_wstrace,
     )
+    from repro.mesh_ws.selfcheck import ORACLE_ATOL, ORACLE_RTOL
     from repro.moe_ws.layer import expert_ffn_nodrop_ref
 
     from benchmarks.moe_dispatch import make_skewed_routing
@@ -103,7 +105,8 @@ def run_one(T, d, f, E, D, k, P, bt, skew, seed=0, trace_sink=None):
             devices_stole=int(tele[:, 5].sum()),
             tiles_stolen=int(tele[:, 6].sum()),
             max_abs_err=float(np.abs(y - ref).max()),
-            bit_identical=bool(np.array_equal(y, ref)),
+            oracle_close=bool(np.allclose(y, ref, rtol=ORACLE_RTOL,
+                                          atol=ORACLE_ATOL)),
             wall_s=round(dt, 3),
         )
     El = E // D
@@ -149,15 +152,12 @@ def main(argv=None):
         name = "BENCH_mesh.dryrun.json" if args.dry_run else "BENCH_mesh.json"
         args.out = str(pathlib.Path(__file__).parent / name)
 
-    import jax
+    from repro.mesh_ws.selfcheck import forced_host_env, forced_host_reexec
 
-    if len(jax.devices()) < args.devices:
-        # the live process initialized jax with fewer devices (the count
-        # locks at first init) — re-exec with the forcing flag in the env
-        env = dict(
-            os.environ,
-            XLA_FLAGS=f"--xla_force_host_platform_device_count={args.devices}",
-        )
+    if forced_host_reexec(args.devices):
+        # CPU backend: run on forced host devices in a child, decided
+        # before this process touches JAX
+        env = forced_host_env(args.devices)
         env.setdefault("PYTHONPATH", str(pathlib.Path(__file__).parent.parent / "src"))
         cmd = [sys.executable, str(pathlib.Path(__file__).resolve()),
                "--skews", args.skews, "--devices", str(args.devices),
@@ -168,6 +168,10 @@ def main(argv=None):
             cmd.append("--dry-run")
         return subprocess.run(cmd, env=env).returncode
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.dry_run:
         T, d, f, E, D, k, P, bt = 48, 8, 16, 16, args.devices, 2, 2, 4
     else:
@@ -177,7 +181,7 @@ def main(argv=None):
     rows = []
     traces = {}
     print("skew,static_makespan,mesh_makespan,speedup,devices_stole,"
-          "tiles_stolen,collective_bytes,bit_identical")
+          "tiles_stolen,collective_bytes,oracle_close")
     for skew in skews:
         sink = {}
         row = run_one(T, d, f, E, D, k, P, bt, skew, trace_sink=sink)
@@ -189,7 +193,7 @@ def main(argv=None):
             f"{row['speedup_vs_static']:.2f},{row['mesh_ws']['devices_stole']},"
             f"{row['mesh_ws']['tiles_stolen']},"
             f"{row['collective_bytes']['measured_mesh_ws']},"
-            f"{row['mesh_ws']['bit_identical']}"
+            f"{row['mesh_ws']['oracle_close']}"
         )
 
     payload = dict(
@@ -209,10 +213,10 @@ def main(argv=None):
               f"{args.trace} — open at https://ui.perfetto.dev")
 
     # headline claims: cross-device stealing wins under skew, and the
-    # dispatch is exact — not approximately, bitwise
+    # dispatch matches the oracle to float32 accumulation order
     bad_exact = [
         r["skew"] for r in rows
-        if not (r["mesh_ws"]["bit_identical"] and r["static"]["bit_identical"])
+        if not (r["mesh_ws"]["oracle_close"] and r["static"]["oracle_close"])
     ]
     if bad_exact:
         print(f"[mesh_dispatch] oracle exactness failed at skews {bad_exact}")

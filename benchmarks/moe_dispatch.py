@@ -232,6 +232,9 @@ def main(argv=None):
                     help="write a Perfetto timeline of the highest-skew ws "
                          "run (load it at https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.out is None:
         # dry-run results go to a sibling file so CI smokes never clobber
         # the committed full-size benchmark

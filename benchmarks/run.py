@@ -115,7 +115,7 @@ def summarize(quick: bool) -> dict:
             tiles_stolen=r["mesh_ws"]["tiles_stolen"],
             collective_bytes_measured=r["collective_bytes"]["measured_mesh_ws"],
             collective_bytes_analytic=r["collective_bytes"]["analytic_mesh_ws"],
-            bit_identical=r["mesh_ws"]["bit_identical"],
+            oracle_close=r["mesh_ws"]["oracle_close"],
         )
     serving = _load("BENCH_serving", quick)
     if serving:
@@ -211,6 +211,9 @@ def main(argv=None):
         default="zero-cost,spanning-tree,scheduler,ragged,moe,policy,mesh,serving,chaos,loader,roofline",
     )
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sections = set(args.sections.split(","))
     t0 = time.time()
 

@@ -238,6 +238,9 @@ def main(argv=None):
     ap.add_argument("--dry-run", action="store_true", help="tiny grid for CI smoke")
     ap.add_argument("--out", default=None, help="output JSON path")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.out is None:
         name = "BENCH_policy.dryrun.json" if args.dry_run else "BENCH_policy.json"
         args.out = str(pathlib.Path(__file__).parent / name)

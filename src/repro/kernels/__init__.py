@@ -3,8 +3,8 @@
 The paper's contribution is synchronization-level (no kernel-level claims);
 these kernels serve the model stack's hot spots per the mandate: fused
 attention (train/prefill), SSD scan (mamba2/zamba2) and split-K decode
-attention.  Each has a pure-jnp oracle in ref.py and is validated in
-interpret mode on CPU; `interpret=False` targets real TPUs.
+attention.  Each has a pure-jnp oracle in ref.py; Pallas interprets them on a CPU
+backend and compiles them on a TPU (:mod:`repro.interpret`).
 """
 
 from .decode_attention.ops import decode_attention

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params as _compiler_params
+from repro.interpret import interpret_mode
 
 NEG_INF = -1e30
 
@@ -77,7 +77,7 @@ def _decode_kernel(
 
 
 def decode_attention(
-    q, k, v, pos, *, window: int = 0, bk: int = 512, interpret: bool = False
+    q, k, v, pos, *, window: int = 0, bk: int = 512
 ):
     """q: [B, H, hd]; k, v: [B, Hkv, S, hd]; pos scalar int32 -> [B, H, hd]."""
     B, H, hd = q.shape
@@ -108,9 +108,9 @@ def decode_attention(
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(pos_arr, q4, k, v)
     return out[:, :, 0, :]

@@ -10,14 +10,14 @@ import jax
 from .kernel import decode_attention as _kernel
 
 
-@functools.partial(jax.jit, static_argnames=("window", "bk", "interpret"))
-def decode_attention(q, k, v, pos, *, window: int = 0, bk: int = 512, interpret: bool = True):
-    return _kernel(q, k, v, pos, window=window, bk=bk, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("window", "bk"))
+def decode_attention(q, k, v, pos, *, window: int = 0, bk: int = 512):
+    return _kernel(q, k, v, pos, window=window, bk=bk)
 
 
 def ragged_decode_attention(
     q, k, v, lengths, *, schedule="ws", n_programs=8, bk=64,
-    interpret=True, return_stats=False,
+    return_stats=False,
 ):
     """Decode attention over ragged KV caches (per-sequence lengths).
 
@@ -30,5 +30,5 @@ def ragged_decode_attention(
 
     return _impl(
         q, k, v, lengths, schedule=schedule, n_programs=n_programs,
-        bk=bk, interpret=interpret, return_stats=return_stats,
+        bk=bk, return_stats=return_stats,
     )

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params as _compiler_params
+from repro.interpret import interpret_mode
 
 NEG_INF = -1e30
 
@@ -90,7 +90,7 @@ def _fwd_kernel(
 
 def flash_attention_fwd(
     q, k, v, *, causal: bool = True, window: int = 0,
-    bq: int = 128, bk: int = 128, interpret: bool = False,
+    bq: int = 128, bk: int = 128,
 ):
     """q: [B, H, S, hd]; k, v: [B, Hkv, S, hd] -> (out, lse [B, H, S])."""
     B, H, S, hd = q.shape
@@ -127,9 +127,9 @@ def flash_attention_fwd(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(q, k, v)
     return out, lse

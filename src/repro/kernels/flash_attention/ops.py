@@ -1,6 +1,6 @@
 """jit'd wrapper for the flash attention kernel, with custom VJP.
 
-Forward: the Pallas kernel (TPU target; `interpret=True` on CPU).
+Forward: the Pallas kernel (compiled on a TPU, interpreted on a CPU).
 Backward: the standard flash backward recomputed from the saved logsumexp,
 written as a chunked pure-jnp pass (O(chunk^2) memory).  On real TPU the
 backward would also be a Pallas kernel; the jnp form keeps the same HLO
@@ -18,24 +18,24 @@ from .kernel import flash_attention_fwd
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
 )
-def flash_attention(q, k, v, causal=True, window=0, bq=128, bk=128, interpret=True):
+def flash_attention(q, k, v, causal=True, window=0, bq=128, bk=128):
     """q: [B, H, S, hd]; k, v: [B, Hkv, S, hd] -> [B, H, S, hd]."""
     out, _ = flash_attention_fwd(
-        q, k, v, causal=causal, window=window, bq=bq, bk=bk, interpret=interpret
+        q, k, v, causal=causal, window=window, bq=bq, bk=bk
     )
     return out
 
 
-def _fwd(q, k, v, causal, window, bq, bk, interpret):
+def _fwd(q, k, v, causal, window, bq, bk):
     out, lse = flash_attention_fwd(
-        q, k, v, causal=causal, window=window, bq=bq, bk=bk, interpret=interpret
+        q, k, v, causal=causal, window=window, bq=bq, bk=bk
     )
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, window, bq, bk, interpret, res, do):
+def _bwd(causal, window, bq, bk, res, do):
     q, k, v, out, lse = res
     B, H, S, hd = q.shape
     Hkv = k.shape[1]
@@ -98,7 +98,7 @@ flash_attention.defvjp(_fwd, _bwd)
 
 def ragged_flash_attention(
     q, k, v, lengths, *, causal=True, schedule="ws", n_programs=8,
-    bq=32, bk=32, interpret=True, return_stats=False,
+    bq=32, bk=32, return_stats=False,
 ):
     """Ragged (variable-length) flash attention.
 
@@ -111,6 +111,5 @@ def ragged_flash_attention(
 
     return _impl(
         q, k, v, lengths, causal=causal, schedule=schedule,
-        n_programs=n_programs, bq=bq, bk=bk, interpret=interpret,
-        return_stats=return_stats,
+        n_programs=n_programs, bq=bq, bk=bk, return_stats=return_stats,
     )

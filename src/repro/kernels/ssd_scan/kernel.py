@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params as _compiler_params
+from repro.interpret import interpret_mode
 
 
 def _ssd_kernel(
@@ -81,7 +81,7 @@ def _ssd_kernel(
         fin_ref[0, 0] = state_scr[...]
 
 
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 128):
     """x: [b, S, H, P]; dt: [b, S, H]; A: [H] f32; B, C: [b, S, N].
 
     Returns (y: [b, S, H, P] in x.dtype, final_state: [b, H, P, N] f32).
@@ -118,10 +118,10 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(xr, dtr, A, Br, Cr)
     y = y.reshape(b, H, S, P).transpose(0, 2, 1, 3)
     return y, fin

@@ -18,19 +18,19 @@ from repro.models.ssm import ssd_chunked
 from .kernel import ssd_scan as _ssd_scan_kernel
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def ssd_scan(x, dt, A, B, C, chunk=128, interpret=True):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def ssd_scan(x, dt, A, B, C, chunk=128):
     """x: [b,S,H,P]; dt: [b,S,H]; A: [H]; B,C: [b,S,N] -> y [b,S,H,P]."""
-    y, _ = _ssd_scan_kernel(x, dt, A, B, C, chunk=chunk, interpret=interpret)
+    y, _ = _ssd_scan_kernel(x, dt, A, B, C, chunk=chunk)
     return y
 
 
-def _fwd(x, dt, A, B, C, chunk, interpret):
-    y, _ = _ssd_scan_kernel(x, dt, A, B, C, chunk=chunk, interpret=interpret)
+def _fwd(x, dt, A, B, C, chunk):
+    y, _ = _ssd_scan_kernel(x, dt, A, B, C, chunk=chunk)
     return y, (x, dt, A, B, C)
 
 
-def _bwd(chunk, interpret, res, dy):
+def _bwd(chunk, res, dy):
     x, dt, A, B, C = res
     _, vjp = jax.vjp(lambda *args: ssd_chunked(*args, chunk=chunk)[0], x, dt, A, B, C)
     return vjp(dy.astype(jnp.result_type(x)))

@@ -1,35 +1,35 @@
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax-importing import: jax locks the device count on
-# first init.  Only the dry-run forces 512 host devices; tests/benches see 1.
+import sys
+import time
 
-import argparse  # noqa: E402
-import json  # noqa: E402
-import sys  # noqa: E402
-import time  # noqa: E402
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from repro.configs import ARCH_IDS, cell_plan, get_config  # noqa: E402
-from repro.launch.hlo_analysis import analyze  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.specs import (  # noqa: E402
+from repro.configs import ARCH_IDS, cell_plan, get_config
+from repro.launch.hlo_analysis import analyze
+from repro.launch.mesh import make_production_mesh
+from repro.launch.specs import (
     attach,
     batch_specs,
     cache_specs,
     opt_state_shardings,
     params_specs,
 )
-from repro.launch.steps import (  # noqa: E402
+from repro.launch.steps import (
     make_decode_step,
     make_optimizer,
     make_prefill_step,
     make_train_step,
     train_policy,
 )
-from repro.models.config import SHAPES  # noqa: E402
-from repro.models.sharding import use_mesh  # noqa: E402
+from repro.models.config import SHAPES
+from repro.models.sharding import use_mesh
+
+# the dry-run lowers for pod-sized meshes on forced host devices; main()
+# sets the count before JAX's CPU backend first initializes
+HOST_DEVICES = 512
 
 # TPU v5e hardware constants (roofline denominators)
 PEAK_FLOPS = 197e12  # bf16 / chip
@@ -213,8 +213,6 @@ def run_cell(
     # undercount); keep it as reference, use the trip-aware HLO walk as
     # the roofline numerator.
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     rec["xla_cost_flops"] = float(ca.get("flops", 0.0))
     rec["xla_cost_bytes"] = float(ca.get("bytes accessed", 0.0))
     res = analyze(compiled.as_text())
@@ -259,6 +257,9 @@ def main(argv=None):
     ap.add_argument("--tag", default="", help="label for the JSONL record")
     ap.add_argument("--out", default=None, help="append-to JSONL path")
     args = ap.parse_args(argv)
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={HOST_DEVICES}"
+    )
 
     rec = run_cell(
         args.arch, args.shape, args.multi_pod, ws_mode=args.ws_mode,

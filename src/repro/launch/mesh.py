@@ -1,6 +1,6 @@
 """Production meshes.  A FUNCTION, not a module constant: importing this
 module must never touch jax device state (the dry-run sets the fake device
-count before first jax init; everything else sees the single real CPU).
+count in its main, before JAX's backend first initializes).
 """
 
 from __future__ import annotations
@@ -9,12 +9,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType only exists on newer jax; older versions are
-    # implicitly Auto on every axis, so omitting the kwarg is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

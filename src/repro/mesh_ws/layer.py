@@ -35,7 +35,6 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.moe_ws.dispatch import divisor_from_tiles
@@ -120,7 +119,6 @@ def mesh_dispatch_body(
     x_flat, idx, gates, wg, wu, wd, *,
     n_experts: int, n_devices: int, bt: int, n_programs: int,
     alpha: int = 1, steal: bool = True, axis: str = MESH_AXIS,
-    interpret: bool = True,
 ):
     """shard_map body of one mesh dispatch step (see module docstring).
 
@@ -148,7 +146,7 @@ def mesh_dispatch_body(
     if not steal:
         res = run_moe_schedule(
             state, xf, put.routed.tok_idx, wg, wu, wd, bt=bt, steal=True,
-            steal_policy="cost", rounds=r2, interpret=interpret,
+            steal_policy="cost", rounds=r2,
         )
         part = _pair_combine_part(put.routed, res.out, res.mult, bt=bt)
         y = _combine_pairs(jax.lax.psum(part, axis), gates)
@@ -162,7 +160,7 @@ def mesh_dispatch_body(
     # ---- phase 1: balanced local drain -----------------------------------
     res1 = run_moe_schedule(
         state, xf, put.routed.tok_idx, wg, wu, wd, bt=bt, steal=True,
-        steal_policy="cost", rounds=r1, interpret=interpret,
+        steal_policy="cost", rounds=r1,
     )
 
     # ---- advisory exchange + victim-context gather -----------------------
@@ -192,7 +190,6 @@ def mesh_dispatch_body(
     res2 = run_moe_schedule(
         state2, xf, put.routed.tok_idx, wg, wu, wd, bt=bt, steal=True,
         steal_policy="cost", rounds=r2, out=res1.out, mult=res1.mult,
-        interpret=interpret,
     )
 
     # ---- phase 2b: execute the stolen remote segment ---------------------
@@ -203,7 +200,7 @@ def mesh_dispatch_body(
     res_s = run_moe_schedule(
         state_s, xf, g_tok[plan.victim], g_wg[plan.victim],
         g_wu[plan.victim], g_wd[plan.victim], bt=bt, steal=True,
-        steal_policy="cost", rounds=r2, interpret=interpret,
+        steal_policy="cost", rounds=r2,
     )
 
     # ---- deliver stolen contributions home, merge multiplicity -----------
@@ -262,7 +259,7 @@ def mesh_wstrace(tele, *, collective_bytes=None):
 def expert_ffn_mesh_ws(
     idx, gates, x, wg, wu, wd, *,
     mesh, bt: int = 8, n_programs: int = 2, alpha: int = 1,
-    steal: bool = True, interpret: bool = True, axis: str = MESH_AXIS,
+    steal: bool = True, axis: str = MESH_AXIS,
     return_telemetry: bool = False,
 ):
     """Router-free mesh twin of :func:`expert_ffn_nodrop_ref`: same argument
@@ -274,13 +271,13 @@ def expert_ffn_mesh_ws(
     body = functools.partial(
         mesh_dispatch_body, n_experts=n_experts, n_devices=n_devices,
         bt=bt, n_programs=n_programs, alpha=alpha, steal=steal,
-        axis=axis, interpret=interpret,
+        axis=axis,
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(axis), P(axis), P(axis)),
         out_specs=(P(), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     y, tele = fn(
         jnp.asarray(x), jnp.asarray(idx, jnp.int32),
@@ -292,7 +289,6 @@ def expert_ffn_mesh_ws(
 def moe_ffn_mesh_ws(
     x, p, cfg, group_size: int = 1024, *,
     mesh=None, bt: int = 8, n_programs: int = 2, alpha: int = 1,
-    interpret: bool = True,
 ):
     """x: [B, S, d] -> (y, aux_loss) — `moe_ffn` drop-in with the dropless
     dispatch sharded over a device mesh (``cfg.moe_dispatch="mesh-ws"``).
@@ -317,7 +313,6 @@ def moe_ffn_mesh_ws(
     y = expert_ffn_mesh_ws(
         idx, gate_vals, x_flat, p["we_g"], p["we_u"], p["we_d"],
         mesh=mesh, bt=bt, n_programs=n_programs, alpha=alpha,
-        interpret=interpret,
     )
     if cfg.n_shared_experts:
         y = y + _shared_experts(x_flat, p).astype(jnp.float32)
@@ -377,7 +372,7 @@ def emulate_mesh_dispatch(
         sl = slice(m * El, (m + 1) * El)
         res1 = run_moe_schedule(
             state, xf, put.routed.tok_idx, wg[sl], wu[sl], wd[sl], bt=bt,
-            steal=True, steal_policy="cost", rounds=r1, interpret=True,
+            steal=True, steal_policy="cost", rounds=r1,
         )
         puts.append(put)
         res1s.append(res1)
@@ -414,7 +409,7 @@ def emulate_mesh_dispatch(
         res2 = run_moe_schedule(
             state2, xf, put.routed.tok_idx, wg[sl], wu[sl], wd[sl], bt=bt,
             steal=True, steal_policy="cost", rounds=r2, out=res1.out,
-            mult=res1.mult, interpret=True,
+            mult=res1.mult,
         )
         res2s.append(res2)
 
@@ -435,7 +430,6 @@ def emulate_mesh_dispatch(
         res_s = run_moe_schedule(
             state_s, xf, vput.routed.tok_idx, wg[vsl], wu[vsl], wd[vsl],
             bt=bt, steal=True, steal_policy="cost", rounds=r2,
-            interpret=True,
         )
         res_ss.append(res_s)
         out_in[v] = out_in[v] + res_s.out

@@ -1,22 +1,53 @@
-"""Forced-device self-check of the mesh dispatch: run seeded routings on N
-fake host devices and assert the shard_map output bit-identical to the
-single-device no-drop oracle.
-
-Run as a module so device forcing precedes first jax init (the dryrun.py
-pattern)::
+"""Self-check of the mesh dispatch: run seeded routings on N devices and
+assert the shard_map output matches the single-device no-drop oracle to
+float32 accumulation order (``ORACLE_RTOL``/``ORACLE_ATOL``), with
+cross-device steals observed::
 
     python -m repro.mesh_ws.selfcheck --devices 8 --seeds 3
 
+On a CPU backend (``JAX_PLATFORMS=cpu``) the N devices are forced host
+devices: the process re-executes itself with
+``--xla_force_host_platform_device_count=N`` in the child's environment,
+deciding so before it touches JAX.  Anywhere else it runs in this one
+process on the real devices — a parent that initialized an accelerator
+would hold it, and a child could not get it.
+
 The tier-1 conformance suite subprocess-runs this (so a 1-device pytest
-session still exercises the real 8-device shard_map path), the CI ``mesh``
-job runs it directly, and ``examples/train_e2e.py --devices N`` reuses the
-routing generator for its forward-parity demo.
+session still exercises the real 8-device shard_map path), and
+``examples/train_e2e.py --devices N`` reuses the routing generator for its
+forward-parity demo.
 """
 
 import argparse
 import json
 import os
 import sys
+
+
+# Mesh dispatch vs the no-drop oracle.  The oracle runs every expert's FFN
+# over every token as batched einsums; the expert tiles compute each
+# expert's rows with their own dot products and the mesh combine sums the
+# top-k pairs itself.  The two agree to float32 accumulation order, not bit
+# for bit (XLA picks the reduction order of each).
+ORACLE_RTOL, ORACLE_ATOL = 1e-5, 1e-6
+
+
+def forced_host_reexec(n_devices: int) -> bool:
+    """Whether a main that needs ``n_devices`` must re-execute itself with
+    forced host devices — decided from the environment alone, before JAX
+    is touched: only on a CPU backend, and only when the forcing flag is
+    not already in place (the child sees it)."""
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        return False
+    flag = f"--xla_force_host_platform_device_count={n_devices}"
+    return flag not in os.environ.get("XLA_FLAGS", "")
+
+
+def forced_host_env(n_devices: int) -> dict:
+    """The child's environment for :func:`forced_host_reexec`."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    flag = f"--xla_force_host_platform_device_count={n_devices}"
+    return dict(os.environ, XLA_FLAGS=f"{flags} {flag}".strip())
 
 
 def skewed_routing(rng, n_tokens: int, n_experts: int, top_k: int,
@@ -63,7 +94,8 @@ def run_checks(n_devices: int, seeds: int, *, n_tokens: int = 24,
         y, ref, tele = np.asarray(y), np.asarray(ref), np.asarray(tele)
         rows.append({
             "seed": seed,
-            "bit_identical": bool(np.array_equal(y, ref)),
+            "close": bool(np.allclose(y, ref, rtol=ORACLE_RTOL,
+                                      atol=ORACLE_ATOL)),
             "max_abs_err": float(np.abs(y - ref).max()),
             "devices_stole": int(tele[:, 5].sum()),
             "tiles_stolen": int(tele[:, 6].sum()),
@@ -77,26 +109,24 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, default=3)
     args = ap.parse_args(argv)
 
-    import jax
-
-    if len(jax.devices()) < args.devices:
-        # this process initialized jax with too few devices (the count locks
-        # at first init) — re-exec with the forcing flag in the child's env,
-        # where it precedes every import
+    if forced_host_reexec(args.devices):
         import subprocess
 
-        env = dict(
-            os.environ,
-            XLA_FLAGS=f"--xla_force_host_platform_device_count={args.devices}",
-        )
         return subprocess.run(
             [sys.executable, "-m", "repro.mesh_ws.selfcheck",
              "--devices", str(args.devices), "--seeds", str(args.seeds)],
-            env=env,
+            env=forced_host_env(args.devices),
         ).returncode
 
+    import jax
+
+    if len(jax.devices()) < args.devices:
+        print(f"FAIL: {args.devices} devices needed, {len(jax.devices())} "
+              "found (JAX_PLATFORMS=cpu forces host devices)", file=sys.stderr)
+        return 1
+
     rows = run_checks(args.devices, args.seeds)
-    ok = all(r["bit_identical"] for r in rows)
+    ok = all(r["close"] for r in rows)
     stole = any(r["devices_stole"] for r in rows)
     print(json.dumps({"devices": args.devices, "ok": ok,
                       "any_steals": stole, "rows": rows}, indent=2))

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.interpret import interpret_mode
 from repro.pallas_ws.kernel import WSRunResult, _attention_execute, launch_ws_grid
 from repro.pallas_ws.queues import QueueState, make_staged_queue_state
 from repro.pallas_ws.ragged import _pad_to
@@ -169,6 +170,20 @@ def unified_step_supported(cfg) -> bool:
     )
 
 
+def require_interpreter() -> None:
+    """The unified launch hands every weight of the model and every
+    activation buffer to one kernel as whole arrays its glue bodies read in
+    full, and its parity contract needs float32: no published width fits a
+    chip's fast memory that way.  It runs in the Pallas interpreter only;
+    on an accelerator backend this raises instead of interpreting."""
+    if not interpret_mode():
+        raise NotImplementedError(
+            "the unified engine step runs in the Pallas interpreter only "
+            f"(backend {jax.default_backend()!r}); serve with the split "
+            "WS decode step (ContinuousBatcher(unified_step=False))"
+        )
+
+
 @dataclass
 class UnifiedStepReport:
     """Telemetry and prefill results of one unified launch."""
@@ -228,6 +243,7 @@ def decode_step_unified(
     acceptance criteria ask for.
     """
     assert unified_step_supported(cfg), cfg.name
+    require_interpreter()
     B = tokens.shape[0]
     L = cfg.n_layers
     H, Hkv = cfg.eff_heads
@@ -700,7 +716,7 @@ def decode_step_unified(
     res = launch_ws_grid(
         state, execute, pure, tuple(outs),
         steal=steal, steal_policy=steal_policy, rounds=rounds,
-        compress_runs=False, stage_open=stage_open, interpret=True,
+        compress_runs=False, stage_open=stage_open,
         trace=trace,
     )
     if check:

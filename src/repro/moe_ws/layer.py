@@ -110,7 +110,6 @@ class _CoreStatic(NamedTuple):
     grad_dispatch: str
     n_programs: int
     bt: int
-    interpret: bool
     steal_run_cap: int = 1
 
 
@@ -241,7 +240,6 @@ def _dispatch_and_run(static: _CoreStatic, x_flat, idx, gate_vals, wg, wu, wd,
         steal_policy=static.steal_policy,
         steal_run_cap=static.steal_run_cap if steal else 1,
         rounds=rounds,
-        interpret=static.interpret,
         trace=trace,
     )
 
@@ -337,7 +335,6 @@ def _grad_ws(static: _CoreStatic, x_flat, idx, gate_vals, wg, wu, wd, gy):
         routed.tok_idx, routed.gates, wg, wu, wd,
         bt=bt, steal=True, steal_policy=static.steal_policy,
         steal_run_cap=static.steal_run_cap, rounds=rounds,
-        interpret=static.interpret,
     )
     # an unexecuted grad tile would contribute exactly-zero gradients (the
     # divisor clamps at 1), so under-provisioning must raise here exactly
@@ -365,7 +362,7 @@ def _assemble_row_grads(res, routed, idx, x_flat, gy, *, bt, d, f, n_experts):
     du = G[:, d: d + f]
     dv = G[:, d + f: d + 2 * f]
     h = G[:, d + 2 * f: d + 3 * f]
-    dgate_rows = G[:, -1]
+    dgate_rows = G[:, d + 3 * f]
 
     tok = jnp.asarray(routed.tok_idx)
     grow = jnp.asarray(routed.gates, jnp.float32)
@@ -445,7 +442,6 @@ def expert_ffn_ws(
     grad_dispatch: str = "dense",
     n_programs: int = 8,
     bt: int = 8,
-    interpret: bool = True,
 ):
     """Router-free routed-expert core on the WS scheduler — the
     differentiable twin of :func:`expert_ffn_nodrop_ref` (same argument
@@ -458,7 +454,7 @@ def expert_ffn_ws(
     static = _CoreStatic(
         n_experts=wg.shape[0], schedule=schedule, steal_policy=steal_policy,
         queue_layout=queue_layout, grad_dispatch=grad_dispatch,
-        n_programs=n_programs, bt=bt, interpret=bool(interpret),
+        n_programs=n_programs, bt=bt,
         steal_run_cap=int(steal_run_cap),
     )
     return _moe_ws_core(
@@ -480,7 +476,6 @@ def moe_ffn_ws(
     grad_dispatch: str = "dense",
     n_programs: int = 8,
     bt: int = 8,
-    interpret: bool = True,
     return_stats: bool = False,
     trace: bool = False,
 ):
@@ -533,7 +528,7 @@ def moe_ffn_ws(
     static = _CoreStatic(
         n_experts=cfg.n_experts, schedule=schedule, steal_policy=steal_policy,
         queue_layout=queue_layout, grad_dispatch=grad_dispatch,
-        n_programs=n_programs, bt=bt, interpret=bool(interpret),
+        n_programs=n_programs, bt=bt,
         steal_run_cap=int(steal_run_cap),
     )
     if return_stats:

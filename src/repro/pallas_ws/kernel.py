@@ -33,10 +33,11 @@ protocol above, because it needs no synchronization at all — a victim chosen
 from arbitrarily stale data costs at most wasted probes, never correctness:
 
 * ``steal_policy="cost"`` (default) — O(1) task-slot loads per round.  An
-  idle program probes its own queue, and on ⊥ picks the victim by one
-  vectorized read of all heads/tails plus the plain-write advisory
-  ``remaining[q]`` cost summary (argmax of remaining work over queues whose
-  head view sits below their tail), then probes exactly one slot.  The
+  idle program probes its own queue, and on ⊥ picks the victim from the
+  heads/tails plus the plain-write advisory ``remaining[q]`` cost summary
+  (the first queue of maximal remaining work among those whose head view
+  sits below their tail — one scalar pass over the queue metadata), then
+  probes exactly one slot.  The
   advisory is updated best-effort by whoever claims a slot (plain read +
   plain write — stale values only mis-rank victims); the ``head < tail``
   mask alone guarantees an idle program claims *some* task whenever any
@@ -59,13 +60,25 @@ plugs in by supplying an ``execute(rec, pure_refs, out_ref)`` body, where
 attention body lives here (:func:`run_ws_schedule`), the MoE expert-FFN body
 in :mod:`repro.moe_ws.expert_kernel`.
 
-Interpret mode (`interpret=True`, the CI path) executes grid cells
-sequentially, which makes single-launch runs sequentially-exact (mult == 1
-everywhere) — duplicates are exercised by seeding adversarial
+Memory spaces (DESIGN.md §3.7).  Every array enters the launch in HBM
+(``memory_space=pltpu.HBM``).  The scheduler state — task records, tails, pool
+offsets, stage-open rounds and the nine mutable queue/telemetry arrays — is
+copied once into SMEM scratch at the launch's first grid cell and back at
+its last, so the protocol above is scalar SMEM loads and stores.  Family
+operands and outputs stay in HBM: each tile copies the blocks it needs into
+VMEM scratch and writes its accumulated output block back, so fast-memory
+use does not grow with slots × capacity.  The DMA semaphores those copies
+wait on belong to one program's own copy engine; nothing shared between
+programs is ever synchronized.  The grid's dimensions are sequential
+("arbitrary"): the programs share queue state, so no dimension may be split
+across cores.
+
+Pallas runs the kernel interpreted on a CPU backend and compiled on a TPU
+(:func:`repro.interpret.interpret_mode`).  The interpreter executes grid
+cells sequentially, which makes single-launch runs sequentially-exact
+(mult == 1 everywhere) — duplicates are exercised by seeding adversarial
 ``head``/``local_head`` snapshots, mirroring the §7 drills of the host
-tests.  On real TPU the queue arrays would sit in SMEM/VMEM and task
-operands would be DMA'd from HBM per task; the protocol itself is
-memory-space agnostic.
+tests.
 """
 
 from __future__ import annotations
@@ -78,7 +91,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..interpret import interpret_mode
 from ..wstrace.ring import (
     EV_COST,
     EV_KIND,
@@ -108,33 +123,49 @@ from .tasks import (
     F_QL,
     F_QS,
     F_TID,
+    TASK_WIDTH,
 )
 
 NEG_INF = -1e30
 
 STEAL_POLICIES = ("cost", "scan")
 
+# Scoped VMEM the compiled megakernel may use for its per-tile operand
+# blocks (v5e has 128 MiB per core; the compiler's default scope is 16 MiB).
+# The expert family's whole-expert weight blocks are what needs the room.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
 # Order of the mutable (input-output aliased) queue/telemetry arrays every
 # family launch carries: head, local_head, taken, remaining, clock, work,
-# steals, scanned, mult, out.  ``launch_ws_grid`` owns this layout.  A
-# multi-output launch (``out`` given as a tuple — the unified engine step)
-# replaces the single ``out`` slot with one slot per output, and a traced
-# launch (``trace=True``) appends two more — the event rings and their
-# per-program cursors (``repro.wstrace.ring``) — after the outputs.
+# steals, scanned, mult, then the family outputs.  ``launch_ws_grid`` owns
+# this layout.  A multi-output launch (``out`` given as a tuple — the
+# unified engine step) carries one slot per output, and a traced launch
+# (``trace=True``) appends two more — the event rings and their per-program
+# cursors (``repro.wstrace.ring``) — after the outputs.  The scheduler
+# arrays are passed flat (``local_head`` as ``[P·n_queues]``, ``taken`` and
+# ``tasks`` row-major) so their SMEM mirrors are 1-D word arrays.
 N_SCHED_MUTABLE = 9   # head..mult, before the family outputs
-N_MUTABLE = 10        # the single-output layout every pre-unified caller uses
 
 
-def _slot_field(tasks_ref, pool_off_ref, v, s, field, *, pool: bool):
-    """Read one int32 field of the task record at queue-slot ``(v, s)``.
+def _slot_row(pool_off_ref, v, s, *, pool: bool, capacity: int):
+    """Flat record row of queue-slot ``(v, s)``.
 
-    Dense layout: ``tasks[v, s, field]``.  Pool layout: queue ``v``'s slots
-    are the contiguous pool segment starting at ``pool_off[v]``, so the same
-    logical slot lives at ``tasks[pool_off[v] + s, field]``.
+    Dense layout: queue ``v`` owns rows ``[v·capacity, (v+1)·capacity)``.
+    Pool layout: queue ``v``'s slots are the contiguous pool segment
+    starting at ``pool_off[v]``, so the same logical slot is row
+    ``pool_off[v] + s``.
     """
     if pool:
-        return tasks_ref[pool_off_ref[v] + s, field]
-    return tasks_ref[v, s, field]
+        return pool_off_ref[v] + s
+    return v * capacity + s
+
+
+def _slot_field(tasks_ref, pool_off_ref, v, s, field, *, pool: bool,
+                capacity: int):
+    """Read one int32 field of the task record at queue-slot ``(v, s)``
+    from the flat ``[rows · TASK_WIDTH]`` record array."""
+    row = _slot_row(pool_off_ref, v, s, pool=pool, capacity=capacity)
+    return tasks_ref[row * TASK_WIDTH + field]
 
 
 def _probe_slot(
@@ -154,7 +185,8 @@ def _probe_slot(
     issue = want & in_range
     op = jax.lax.cond(
         issue,
-        lambda: _slot_field(tasks_ref, pool_off_ref, v, h, F_OP, pool=pool),
+        lambda: _slot_field(tasks_ref, pool_off_ref, v, h, F_OP, pool=pool,
+                            capacity=capacity),
         lambda: jnp.int32(BOTTOM),
     )
     return op, issue.astype(jnp.int32)
@@ -207,9 +239,13 @@ def ws_try_extract(
     def stage_open(v):
         return jnp.bool_(True) if stage_ref is None else stage_ref[v] <= r
 
+    def lh(v):
+        # flat [P·n_queues] local bounds: program p's row starts at p·n_queues
+        return p * n_queues + v
+
     def claim_writes(v, h):
-        head_ref[v] = h + 1            # plain write — no CAS
-        local_head_ref[p, v] = h + 1   # persistent local bound
+        head_ref[v] = h + 1              # plain write — no CAS
+        local_head_ref[lh(v)] = h + 1    # persistent local bound
 
     def scan_extract():
         """PR-1 policy: p-relative sequential scan over every queue."""
@@ -217,7 +253,7 @@ def ws_try_extract(
         def scan_one(j, carry):
             found, fq, fs, nread = carry
             v = jax.lax.rem(p + j, n_queues)
-            h = jnp.maximum(local_head_ref[p, v], head_ref[v])  # RMaxRead
+            h = jnp.maximum(local_head_ref[lh(v)], head_ref[v])  # RMaxRead
             op, issued = probe(v, h, (~found) & stage_open(v))
             live = op != BOTTOM
             claim = (~found) & live
@@ -241,7 +277,7 @@ def ws_try_extract(
     def cost_extract():
         """O(1) policy: own-queue probe, then cost-aware victim argmax."""
         own = jax.lax.rem(p, n_queues)
-        h0 = jnp.maximum(local_head_ref[p, own], head_ref[own])  # RMaxRead
+        h0 = jnp.maximum(local_head_ref[lh(own)], head_ref[own])  # RMaxRead
         op0, issued0 = probe(own, h0, stage_open(own))
         own_live = op0 != BOTTOM
 
@@ -252,21 +288,26 @@ def ws_try_extract(
         if not steal:
             return own_live, own, h0, jnp.int32(1), issued0
 
-        # Victim selection from plain vector reads — no slot loads.  The
-        # `heads < tails` mask is exact for any state the protocol can
-        # reach (head never passes tail), so an idle program always finds
-        # a claimable victim when one exists; the advisory only *ranks*
-        # the stealable queues, so arbitrary staleness costs ordering,
-        # never progress (max(adv, 1) keeps zeroed advisories claimable).
-        lh = local_head_ref[pl.ds(p, 1), :].reshape(n_queues)
-        heads = jnp.maximum(lh, head_ref[:])
-        stealable = heads < tail_ref[:]
-        if stage_ref is not None:
-            stealable &= stage_ref[:] <= r
-        score = jnp.where(stealable, jnp.maximum(remaining_ref[:], 1), 0)
-        v = jnp.argmax(score).astype(jnp.int32)
-        can = (~own_live) & (jnp.max(score) > 0)
-        h = heads[v]
+        # Victim selection from plain scalar reads of the queue metadata —
+        # no slot loads.  The `head < tail` mask is exact for any state the
+        # protocol can reach (head never passes tail), so an idle program
+        # always finds a claimable victim when one exists; the advisory
+        # only *ranks* the stealable queues, so arbitrary staleness costs
+        # ordering, never progress (max(adv, 1) keeps zeroed advisories
+        # claimable).  The strict `>` keeps the first maximal queue, the
+        # victim an argmax over the score vector picks.
+        def rank(j, carry):
+            best, bv, bh = carry
+            hj = jnp.maximum(local_head_ref[lh(j)], head_ref[j])
+            ok = (hj < tail_ref[j]) & stage_open(j)
+            score = jnp.where(ok, jnp.maximum(remaining_ref[j], 1), 0)
+            better = score > best
+            return (jnp.where(better, score, best), jnp.where(better, j, bv),
+                    jnp.where(better, hj, bh))
+
+        zero3 = (jnp.int32(0), jnp.int32(0), jnp.int32(0))
+        best, v, h = jax.lax.fori_loop(0, n_queues, rank, zero3)
+        can = (~own_live) & (best > 0)
         op, issued = probe(v, h, can)
         live = can & (op != BOTTOM)
 
@@ -287,8 +328,8 @@ def ws_try_extract(
 
             @pl.when(live)
             def _steal():
-                head_ref[v] = h + take           # plain write — no CAS
-                local_head_ref[p, v] = h + take  # persistent local bound
+                head_ref[v] = h + take             # plain write — no CAS
+                local_head_ref[lh(v)] = h + take   # persistent local bound
 
         found = own_live | live
         fq = jnp.where(own_live, own, v)
@@ -306,7 +347,8 @@ def ws_account(
     r, p, fq, fs, tid, cost,
     taken_ref, remaining_ref, clock_ref, work_ref, steals_ref, mult_ref,
     pool_off_ref=None,
-    *, n_queues: int, pool: bool = False, advisory: bool = True,
+    *, n_queues: int, capacity: int, pool: bool = False,
+    advisory: bool = True,
 ):
     """Post-execution bookkeeping shared by every task family: announcement
     row, multiplicity counter, work/steal telemetry, lockstep clock bump,
@@ -319,10 +361,7 @@ def ws_account(
     commutes (``max(max(r-c1,0)-c2,0) == max(r-c1-c2,0)`` for nonnegative
     costs), so the coalesced value is bit-identical."""
     mult_ref[tid] = mult_ref[tid] + 1
-    if pool:
-        taken_ref[pool_off_ref[fq] + fs] = p
-    else:
-        taken_ref[fq, fs] = p
+    taken_ref[_slot_row(pool_off_ref, fq, fs, pool=pool, capacity=capacity)] = p
     if advisory:
         remaining_ref[fq] = jnp.maximum(remaining_ref[fq] - cost, 0)
     work_ref[p] = work_ref[p] + cost
@@ -333,55 +372,108 @@ def ws_account(
 
 def _generic_ws_kernel(
     *refs,
-    execute: Callable,
     n_pure: int,
+    n_smem_pure: int,
+    n_outs: int,
+    pool: bool,
+    staged: bool,
+    trace: bool,
+    **cell_kw,
+):
+    """Launch shell: SMEM mirrors of the scheduler state around the
+    per-cell protocol (:func:`_ws_cell`).
+
+    Ref layout (positional, fixed by :func:`launch_ws_grid`), every array in
+    HBM: the mutable inputs (9 flat scheduler arrays, ``n_outs`` family
+    outputs, + event rings and cursors when ``trace``), the scheduler's
+    read-only inputs (flat task records, tails, the pool segment offsets
+    when ``pool``, the stage-open rounds when ``staged``), the
+    ``n_smem_pure`` family inputs the body reads as scalars, the ``n_pure``
+    family inputs it copies blocks of; then the aliased outputs in the order
+    of the mutable inputs; then the SMEM scratch — one mirror per scheduler
+    array (mutable, then read-only) and per scalar family input, plus the
+    cursor mirror and one event-record row when ``trace``.
+
+    The first grid cell copies the scheduler state into its SMEM mirrors
+    (reading the mutable arrays through their aliased output refs, which
+    hold the input values); the last cell copies the mutable mirrors back.
+    In between, every cell is plain scalar SMEM loads and stores.
+    """
+    n_mut = N_SCHED_MUTABLE + n_outs + (2 if trace else 0)
+    n_ro = 2 + int(pool) + int(staged) + n_smem_pure
+    n_in = n_mut + n_ro + n_pure
+    ro_in = refs[n_mut: n_mut + n_ro]
+    hbm_pure = refs[n_mut + n_ro: n_in]
+    outs = refs[n_in: n_in + n_mut]
+    smem = refs[n_in + n_mut:]
+    sched_mut = smem[:N_SCHED_MUTABLE]
+    ro = smem[N_SCHED_MUTABLE: N_SCHED_MUTABLE + n_ro]
+    hbm_mut = outs[:N_SCHED_MUTABLE]
+    if trace:
+        ev_ref, cursor_hbm = outs[N_SCHED_MUTABLE + n_outs:]
+        cursor_ref, ev_row_ref = smem[N_SCHED_MUTABLE + n_ro:]
+        mirrored = tuple(sched_mut) + (cursor_ref,)
+        hbm_mut = tuple(hbm_mut) + (cursor_hbm,)
+    else:
+        ev_ref = cursor_ref = ev_row_ref = None
+        mirrored = tuple(sched_mut)
+
+    r = pl.program_id(0)
+    p = pl.program_id(1)
+    first = (r == 0) & (p == 0)
+    last = (r == pl.num_programs(0) - 1) & (p == pl.num_programs(1) - 1)
+
+    @pl.when(first)
+    def _load():
+        pltpu.sync_copy((tuple(hbm_mut), tuple(ro_in)), (mirrored, tuple(ro)))
+
+    tasks_ref, tail_ref = ro[:2]
+    pool_off_ref = ro[2] if pool else None
+    stage_ref = ro[2 + int(pool)] if staged else None
+    pure = tuple(ro[n_ro - n_smem_pure:]) + tuple(hbm_pure)
+    out_refs = outs[N_SCHED_MUTABLE: N_SCHED_MUTABLE + n_outs]
+    _ws_cell(
+        r, p, *sched_mut, tasks_ref, tail_ref, pool_off_ref, stage_ref,
+        pure, out_refs, ev_ref, cursor_ref, ev_row_ref,
+        pool=pool, staged=staged, trace=trace, **cell_kw,
+    )
+
+    @pl.when(last)
+    def _store():
+        pltpu.sync_copy(mirrored, tuple(hbm_mut))
+
+
+def _ws_cell(
+    r, p,
+    head_ref, local_head_ref, taken_ref, remaining_ref, clock_ref, work_ref,
+    steals_ref, scanned_ref, mult_ref,
+    tasks_ref, tail_ref, pool_off_ref, stage_ref,
+    pure, out_refs, ev_ref, ev_cursor_ref, ev_row_ref,
+    *,
+    execute: Callable,
     n_queues: int,
     capacity: int,
     steal: bool,
     steal_policy: str,
     pool: bool,
     compress: bool,
-    steal_run_cap: int = 1,
-    n_outs: int = 1,
-    multi_out: bool = False,
-    staged: bool = False,
-    trace: bool = False,
-    trace_capacity: int = 0,
-    steal_kind: int = KIND_STEAL_COST,
+    steal_run_cap: int,
+    multi_out: bool,
+    staged: bool,
+    trace: bool,
+    trace_capacity: int,
+    steal_kind: int,
 ):
-    """Scheduler shell around a family ``execute`` body.
-
-    Ref layout (positional, fixed by :func:`launch_ws_grid`): the mutable
-    stale input snapshots (9 scheduler arrays + ``n_outs`` outputs, +2 when
-    ``trace``), the tasks array, the (static) tails, the pool segment
-    offsets when ``pool``, the stage-open rounds when ``staged``, ``n_pure``
-    family inputs, then the live (aliased) output refs in the same order as
-    the snapshots.
+    """One grid cell of the persistent WS grid: program ``p`` at round
+    ``r`` tries one Take/Steal (or, compressed, drains its own queue) and
+    runs the family ``execute`` body on each claimed record.
 
     ``multi_out`` launches call ``execute(rec, pure, outs, mult_ref)`` with
     the tuple of output refs plus the live multiplicity counters (the
     unified step's glue phases normalize accumulators in-kernel); the
     single-output convention stays ``execute(rec, pure, out_ref)``.
     """
-    n_live = N_SCHED_MUTABLE + n_outs
-    n_mut = n_live + (2 if trace else 0)
-    tasks_ref = refs[n_mut]
-    tail_ref = refs[n_mut + 1]
-    off = n_mut + 2
-    pool_off_ref = refs[off] if pool else None
-    off += int(pool)
-    stage_ref = refs[off] if staged else None
-    off += int(staged)
-    pure = refs[off: off + n_pure]
-    live = refs[off + n_pure:]
-    (head_ref, local_head_ref, taken_ref, remaining_ref, clock_ref, work_ref,
-     steals_ref, scanned_ref, mult_ref) = live[:N_SCHED_MUTABLE]
-    out_refs = live[N_SCHED_MUTABLE:n_live]
     out_ref = out_refs if multi_out else out_refs[0]
-    ev_ref, ev_cursor_ref = live[n_live:] if trace else (None, None)
-
-    r = pl.program_id(0)
-    p = pl.program_id(1)
 
     def trace_append(fq, fs, tid, cost, t0, op, run):
         """Append one extraction record to program ``p``'s event ring —
@@ -403,23 +495,23 @@ def _generic_ws_kernel(
 
         @pl.when(c < trace_capacity)
         def _append():
-            ev_ref[p, c, EV_ROUND] = t0
-            ev_ref[p, c, EV_PROG] = p
-            ev_ref[p, c, EV_QUEUE] = fq
-            ev_ref[p, c, EV_SLOT] = fs
-            ev_ref[p, c, EV_TID] = tid
-            ev_ref[p, c, EV_COST] = cost
-            ev_ref[p, c, EV_KIND] = kind
-            ev_ref[p, c, EV_VICTIM] = victim
-            ev_ref[p, c, EV_MULT] = mult_ref[tid]
-            ev_ref[p, c, EV_OP] = op
-            ev_ref[p, c, EV_RUN] = run
+            # assemble the record in SMEM, then one small copy into the
+            # program's ring row in HBM (the rings outgrow SMEM)
+            for field, val in (
+                (EV_ROUND, t0), (EV_PROG, p), (EV_QUEUE, fq), (EV_SLOT, fs),
+                (EV_TID, tid), (EV_COST, cost), (EV_KIND, kind),
+                (EV_VICTIM, victim), (EV_MULT, mult_ref[tid]), (EV_OP, op),
+                (EV_RUN, run),
+            ):
+                ev_row_ref[field] = jnp.asarray(val, jnp.int32)
+            pltpu.sync_copy(ev_row_ref, ev_ref.at[p, c])
 
         ev_cursor_ref[p] = c + 1
 
     def account(fq, fs, advisory=True, run=1):
         rec = functools.partial(
-            _slot_field, tasks_ref, pool_off_ref, fq, fs, pool=pool
+            _slot_field, tasks_ref, pool_off_ref, fq, fs, pool=pool,
+            capacity=capacity,
         )
         if trace:
             # virtual start of this execution — read before ws_account bumps
@@ -434,8 +526,8 @@ def _generic_ws_kernel(
         ws_account(
             r, p, fq, fs, rec(F_TID), rec(F_COST),
             taken_ref, remaining_ref, clock_ref, work_ref, steals_ref,
-            mult_ref, pool_off_ref, n_queues=n_queues, pool=pool,
-            advisory=advisory,
+            mult_ref, pool_off_ref, n_queues=n_queues, capacity=capacity,
+            pool=pool, advisory=advisory,
         )
         if trace:
             trace_append(fq, fs, rec(F_TID), rec(F_COST), t0, rec(F_OP), run)
@@ -453,7 +545,7 @@ def _generic_ws_kernel(
         own = jax.lax.rem(p, n_queues)
 
         def probe_own():
-            h = jnp.maximum(local_head_ref[p, own], head_ref[own])
+            h = jnp.maximum(local_head_ref[p * n_queues + own], head_ref[own])
             op, issued = _probe_slot(
                 tasks_ref, pool_off_ref, tail_ref, own, h, jnp.bool_(True),
                 pool=pool, capacity=capacity,
@@ -469,7 +561,7 @@ def _generic_ws_kernel(
             def body(carry):
                 _, h, acc = carry
                 head_ref[own] = h + 1
-                local_head_ref[p, own] = h + 1
+                local_head_ref[p * n_queues + own] = h + 1
                 cost = account(own, h, advisory=False)
                 live, nh = probe_own()
                 return live, nh, acc + cost
@@ -646,17 +738,23 @@ def launch_ws_grid(
     mult: Optional[jax.Array] = None,
     compress_runs: Optional[bool] = None,
     stage_open: Optional[jax.Array] = None,
-    interpret: bool = True,
     trace: bool = False,
     trace_capacity: Optional[int] = None,
     trace_remote: bool = False,
     fault_plan=None,
+    smem_pure: Sequence[jax.Array] = (),
 ) -> WSRunResult:
     """Run the persistent WS grid with a family ``execute`` body.
 
     ``execute(rec, pure_refs, out_ref)`` performs the claimed tile —
     ``rec(field)`` reads one field of its task record — and *accumulates*
     into ``out_ref``; the shell handles extraction and bookkeeping.
+    ``pure_refs`` are the ``smem_pure`` arrays (1-D inputs the body reads
+    as scalars, mirrored into SMEM once per launch — the expert family's
+    row → token map and row gates) followed by the ``pure`` arrays, which stay in HBM:
+    the body copies the blocks it needs into VMEM scratch
+    (``pltpu.sync_copy``), and likewise reads and writes back its output
+    blocks.
     ``out``/``mult`` may be carried over from a previous launch (resume /
     multiplicity drills).  ``compress_runs`` defaults to ``not steal``:
     no-steal launches drain whole owner runs per grid cell (§3.6), steal
@@ -762,6 +860,7 @@ def launch_ws_grid(
         _generic_ws_kernel,
         execute=execute,
         n_pure=len(pure),
+        n_smem_pure=len(smem_pure),
         n_queues=state.n_queues,
         capacity=state.capacity,
         steal=steal,
@@ -777,43 +876,56 @@ def launch_ws_grid(
         steal_kind=steal_kind,
     )
 
-    def full(a):
-        return pl.BlockSpec(a.shape, lambda r, p, nd=a.ndim: (0,) * nd)
-
-    mutable = [
-        jnp.asarray(state.head),
-        jnp.asarray(state.local_head),
-        jnp.asarray(state.taken),
-        jnp.asarray(remaining, dtype=jnp.int32),
+    i32 = jnp.int32
+    sched = [
+        jnp.asarray(state.head, i32),
+        jnp.asarray(state.local_head, i32).reshape(-1),
+        jnp.asarray(state.taken, i32).reshape(-1),
+        jnp.asarray(remaining, i32),
         clock0,                       # clock (stall faults start nonzero)
-        jnp.zeros((P,), jnp.int32),   # work
-        jnp.zeros((P,), jnp.int32),   # steals
-        jnp.zeros((P,), jnp.int32),   # scanned
-        jnp.asarray(mult),
-    ] + [jnp.asarray(o) for o in outs_in]
+        jnp.zeros((P,), i32),         # work
+        jnp.zeros((P,), i32),         # steals
+        jnp.zeros((P,), i32),         # scanned
+        jnp.asarray(mult, i32),
+    ]
+    mutable = sched + [jnp.asarray(o) for o in outs_in]
     if trace:
         mutable += [
-            jnp.full((P, trace_capacity, EVENT_WIDTH), -1, jnp.int32),
-            jnp.zeros((P,), jnp.int32),  # event cursors
+            jnp.full((P, trace_capacity, EVENT_WIDTH), -1, i32),
+            jnp.zeros((P,), i32),  # event cursors
         ]
-    pure_arrays = [jnp.asarray(state.tasks), jnp.asarray(state.tail)]
+    sched_in = [jnp.asarray(state.tasks, i32).reshape(-1),
+                jnp.asarray(state.tail, i32)]
     if pool:
-        pure_arrays.append(jnp.asarray(state.pool_off))
+        sched_in.append(jnp.asarray(state.pool_off, i32))
     if stage_open is not None:
-        pure_arrays.append(jnp.asarray(stage_open, dtype=jnp.int32))
-    pure_arrays += [jnp.asarray(a) for a in pure]
+        sched_in.append(jnp.asarray(stage_open, i32))
+    sched_in += [jnp.asarray(a) for a in smem_pure]
+    pure_arrays = [jnp.asarray(a) for a in pure]
+    smem = [pltpu.SMEM(a.shape, a.dtype) for a in sched + sched_in]
+    if trace:
+        smem += [pltpu.SMEM((P,), i32), pltpu.SMEM((EVENT_WIDTH,), i32)]
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    n_in = len(mutable) + len(sched_in) + len(pure_arrays)
     outs = pl.pallas_call(
         kernel,
         grid=(rounds, P),
-        in_specs=[full(a) for a in mutable] + [full(a) for a in pure_arrays],
-        out_specs=[full(a) for a in mutable],
+        in_specs=[hbm] * n_in,
+        out_specs=[hbm] * len(mutable),
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in mutable],
+        scratch_shapes=smem,
         input_output_aliases={i: i for i in range(len(mutable))},
-        interpret=interpret,
-    )(*mutable, *pure_arrays)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret_mode(),
+    )(*mutable, *sched_in, *pure_arrays)
     n_live = N_SCHED_MUTABLE + len(outs_in)
     (head, local_head, taken, remaining, clock, work, steals, scanned,
      mult) = outs[:N_SCHED_MUTABLE]
+    local_head = local_head.reshape(np.shape(state.local_head))
+    taken = taken.reshape(np.shape(state.taken))
     out = (
         tuple(outs[N_SCHED_MUTABLE:n_live]) if multi_out
         else outs[N_SCHED_MUTABLE]
@@ -852,61 +964,80 @@ def _attention_execute(
     *, bq: int, bk: int, causal: bool, scale: float, g: int,
 ):
     """Flash-attention tile: online-softmax sweep of the task's kv range,
-    accumulated into the task's disjoint q-block rows."""
+    accumulated into the task's disjoint q-block rows.
+
+    ``q``/``k``/``v`` and the output stay in HBM: the tile copies its q
+    block, then each kv block in turn, into VMEM scratch, and finally
+    reads, adds to and writes back its own output block."""
     q_ref, k_ref, v_ref = pure
     b = rec(F_B)
     h = rec(F_H)
-    qs = rec(F_QS)
+    qs = pl.multiple_of(rec(F_QS), bq)
     ql = rec(F_QL)
     kv_end = rec(F_KV)
     cost = rec(F_COST)
     kh = jax.lax.div(h, g)
-
-    qt = q_ref[pl.ds(b, 1), pl.ds(h, 1), pl.ds(qs, bq), :]
-    qt = qt.reshape(bq, q_ref.shape[-1]).astype(jnp.float32)
-
-    def kv_block(ki, mla):
-        m, l, acc = mla
-        kt = k_ref[pl.ds(b, 1), pl.ds(kh, 1), pl.ds(ki * bk, bk), :]
-        vt = v_ref[pl.ds(b, 1), pl.ds(kh, 1), pl.ds(ki * bk, bk), :]
-        kt = kt.reshape(bk, -1).astype(jnp.float32)
-        vt = vt.reshape(bk, -1).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qt, kt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [bq, bk]
-        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        valid = kpos < kv_end
-        if causal:
-            qpos = qs + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            valid &= kpos <= qpos
-        s = jnp.where(valid, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        pexp = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + pexp.sum(axis=1)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            pexp, vt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return (m_new, l_new, acc_new)
-
     hd = q_ref.shape[-1]
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    a0 = jnp.zeros((bq, hd), jnp.float32)
-    # Dynamic trip count: a real persistent core sweeps only the live
-    # blocks — this is exactly the cost the work counters account.
-    m, l, acc = jax.lax.fori_loop(0, cost, kv_block, (m0, l0, a0))
 
-    tile = acc / jnp.maximum(l, 1e-30)[:, None]
-    row_live = jax.lax.broadcasted_iota(jnp.int32, (bq, hd), 0) < ql
-    tile = jnp.where(row_live, tile, 0.0)
+    def body(q_buf, k_buf, v_buf, o_buf):
+        pltpu.sync_copy(q_ref.at[b, h, pl.ds(qs, bq)], q_buf)
+        qt = q_buf[...].astype(jnp.float32)
 
-    # Idempotent-accumulate: duplicates add whole extra copies of the
-    # same tile, which mult[tid] normalizes out host-side.
-    cur = out_ref[pl.ds(b, 1), pl.ds(h, 1), pl.ds(qs, bq), :]
-    out_ref[pl.ds(b, 1), pl.ds(h, 1), pl.ds(qs, bq), :] = cur + tile[None, None]
+        def kv_block(ki, mla):
+            m, l, acc = mla
+            start = pl.multiple_of(ki * bk, bk)
+            pltpu.sync_copy(
+                (k_ref.at[b, kh, pl.ds(start, bk)],
+                 v_ref.at[b, kh, pl.ds(start, bk)]),
+                (k_buf, v_buf),
+            )
+            kt = k_buf[...].astype(jnp.float32)
+            vt = v_buf[...].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                qt, kt, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [bq, bk]
+            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            valid = kpos < kv_end
+            if causal:
+                qpos = qs + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+                valid &= kpos <= qpos
+            s = jnp.where(valid, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+            pexp = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + pexp.sum(axis=1, keepdims=True)
+            acc_new = acc * corr + jax.lax.dot_general(
+                pexp, vt, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return (m_new, l_new, acc_new)
+
+        m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((bq, 1), jnp.float32)
+        a0 = jnp.zeros((bq, hd), jnp.float32)
+        # Dynamic trip count: a real persistent core sweeps only the live
+        # blocks — this is exactly the cost the work counters account.
+        m, l, acc = jax.lax.fori_loop(0, cost, kv_block, (m0, l0, a0))
+
+        tile = acc / jnp.maximum(l, 1e-30)
+        row_live = jax.lax.broadcasted_iota(jnp.int32, (bq, hd), 0) < ql
+        tile = jnp.where(row_live, tile, 0.0)
+
+        # Idempotent-accumulate: duplicates add whole extra copies of the
+        # same tile, which mult[tid] normalizes out host-side.
+        dst = out_ref.at[b, h, pl.ds(qs, bq)]
+        pltpu.sync_copy(dst, o_buf)
+        o_buf[...] = o_buf[...] + tile
+        pltpu.sync_copy(o_buf, dst)
+
+    pl.run_scoped(
+        body,
+        pltpu.VMEM((bq, hd), q_ref.dtype),
+        pltpu.VMEM((bk, hd), k_ref.dtype),
+        pltpu.VMEM((bk, hd), v_ref.dtype),
+        pltpu.VMEM((bq, hd), out_ref.dtype),
+    )
 
 
 def run_ws_schedule(
@@ -925,7 +1056,6 @@ def run_ws_schedule(
     out: Optional[jax.Array] = None,
     mult: Optional[jax.Array] = None,
     compress_runs: Optional[bool] = None,
-    interpret: bool = True,
     trace: bool = False,
     trace_capacity: Optional[int] = None,
     fault_plan=None,
@@ -951,6 +1081,6 @@ def run_ws_schedule(
         state, execute, (q, k, v), out,
         steal=steal, steal_policy=steal_policy, steal_run_cap=steal_run_cap,
         rounds=rounds, mult=mult,
-        compress_runs=compress_runs, interpret=interpret,
+        compress_runs=compress_runs,
         trace=trace, trace_capacity=trace_capacity, fault_plan=fault_plan,
     )

@@ -116,7 +116,6 @@ def ragged_flash_attention(
     partition: str = "batch",
     bq: int = 32,
     bk: int = 32,
-    interpret: bool = True,
     return_stats: bool = False,
     trace: bool = False,
 ):
@@ -145,7 +144,7 @@ def ragged_flash_attention(
         causal=causal, bq=bq, bk=bk,
         steal=(schedule == "ws"), steal_policy=steal_policy,
         steal_run_cap=steal_run_cap if schedule == "ws" else 1,
-        interpret=interpret, trace=trace,
+        trace=trace,
     )
     _check_drained(state, res)
     div = multiplicity_divisor(tasks, res.mult, (B, H, qp.shape[2]))
@@ -219,7 +218,6 @@ def ragged_decode_attention(
     n_programs: int = 8,
     partition: str = "batch",
     bk: int = 64,
-    interpret: bool = True,
     return_stats: bool = False,
     trace: bool = False,
 ):
@@ -260,7 +258,10 @@ def ragged_decode_attention(
         tasks = emit_decode_tasks(lengths, H, bk)
         state = make_queue_state(tasks, n_programs, partition=partition)
         rounds = None
-    q4 = q[:, :, None, :]
+    # one query row per tile: float32 keeps that row aligned to the chip's
+    # (1, 128) tiling (a packed bf16 row is half a tile); the tile body
+    # computes in float32 either way
+    q4 = q.astype(jnp.float32)[:, :, None, :]
     kp = _pad_to(k, 2, bk)
     vp = _pad_to(v, 2, bk)
     res = run_ws_schedule(
@@ -268,7 +269,7 @@ def ragged_decode_attention(
         causal=False, bq=1, bk=bk,
         steal=steal, steal_policy=steal_policy,
         steal_run_cap=steal_run_cap if steal else 1, rounds=rounds,
-        interpret=interpret, trace=trace,
+        trace=trace,
     )
     if traced:
         # tid = b·H + h is static: the divisor is just the reshaped

@@ -8,8 +8,8 @@ Two layers:
   engine step decodes all live slots in one decode step — by default
   `decode_step_ws`, which schedules the slots' ragged attention (and, with
   `cfg.moe_dispatch == "ws"`, the expert FFN) as tile tasks on the
-  fence-free work-stealing megakernel; `use_ws=False` falls back to the
-  jitted dense decode_step.  On a multi-device host, `cfg.moe_dispatch ==
+  fence-free work-stealing megakernel; `use_ws=False` selects the jitted
+  dense decode_step.  On a multi-device host, `cfg.moe_dispatch ==
   "mesh-ws"` shards the expert FFN's queues over the mesh "model" axis
   instead (repro.mesh_ws, DESIGN.md §7) — serving is the mesh dispatch's
   primary consumer, since it is forward-only.  Finished slots free
@@ -49,6 +49,7 @@ from repro.models import (
     unified_step_supported,
     ws_decode_supported,
 )
+from repro.models.unified import require_interpreter
 from repro.wstrace.metrics import SchedulerMetrics
 
 
@@ -68,11 +69,11 @@ def jit_decode_step_ws(cfg, *, schedule: str = "ws", bk: int = 64,
     """
     from repro.models import decode_step_ws as _ws
 
-    return jax.jit(
-        lambda p, c, t, pos: _ws(
-            p, cfg, c, t, pos, schedule=schedule, bk=bk, n_programs=n_programs
-        )
-    )
+    def ws_decode_step(p, c, t, pos):
+        return _ws(p, cfg, c, t, pos, schedule=schedule, bk=bk,
+                   n_programs=n_programs)
+
+    return jax.jit(ws_decode_step)
 
 
 @dataclass
@@ -112,25 +113,34 @@ class ContinuousBatcher:
         self.temperature = float(temperature)
         # seeded host-side sampler so greedy=False runs are reproducible
         self._rng = np.random.default_rng(sample_seed)
-        # Decode attention schedule: with `use_ws` (the default, for the
-        # architectures decode_step_ws covers) every engine step routes the
-        # slots' ragged lengths through the repro.pallas_ws scheduler
-        # ("ws" steals, "static" drains owner queues).  `jit_ws` compiles
-        # that whole step — queues built by the traced Put on device —
-        # instead of re-building queues host-side each iteration.
-        # `use_ws=False` is the escape hatch back to the jitted dense
-        # decode_step.
+        # Decode attention schedule: with `use_ws` (the default) every
+        # engine step routes the slots' ragged lengths through the
+        # repro.pallas_ws scheduler ("ws" steals, "static" drains owner
+        # queues).  `jit_ws` compiles that whole step — queues built by the
+        # traced Put on device — instead of re-building queues host-side
+        # each iteration.  `use_ws=False` selects the jitted dense
+        # decode_step; an architecture decode_step_ws does not cover must
+        # ask for it, so a WS run never turns dense behind the caller's back.
         if attn_schedule not in ("ws", "static"):
             raise ValueError(f"attn_schedule must be 'ws' or 'static': {attn_schedule!r}")
         self.attn_schedule = attn_schedule
-        self.use_ws = bool(use_ws and ws_decode_supported(cfg))
+        if use_ws and not ws_decode_supported(cfg):
+            raise ValueError(
+                f"use_ws=True: decode_step_ws does not cover {cfg.name!r} "
+                "(it serves full-attention GQA decoders); pass use_ws=False "
+                "for the dense decode step"
+            )
+        self.use_ws = bool(use_ws)
         # Unified mode: ONE launch_ws_grid launch per engine step carries the
         # decode tiles, at most one admitted prompt's prefill tiles, and (MoE)
         # the expert tiles (models.unified, DESIGN.md §5).  admit() defers
         # the prefill into the next step instead of running it standalone;
         # the split-launch path below stays as the escape hatch and oracle.
-        if unified_step and not unified_step_supported(cfg):
-            raise ValueError(f"unified_step unsupported for config {cfg.name!r}")
+        # It runs interpreted only: on an accelerator it raises here.
+        if unified_step:
+            if not unified_step_supported(cfg):
+                raise ValueError(f"unified_step unsupported for config {cfg.name!r}")
+            require_interpreter()
         self.unified = bool(unified_step)
         self._pending = deque()          # (slot, Request) awaiting prefill
         self._pending_slots: set = set()
@@ -141,12 +151,15 @@ class ContinuousBatcher:
                 p, cfg, c, t, pos, schedule=attn_schedule
             )
         else:
-            self._decode = jax.jit(
-                lambda p, c, t, pos: decode_step(p, cfg, c, t, pos)
-            )
-        self._prefill = jax.jit(
-            lambda p, b, cap=capacity: prefill(p, cfg, b, capacity=cap)
-        )
+            def dense_decode_step(p, c, t, pos):
+                return decode_step(p, cfg, c, t, pos)
+
+            self._decode = jax.jit(dense_decode_step)
+
+        def prefill_step(p, b):
+            return prefill(p, cfg, b, capacity=capacity)
+
+        self._prefill = jax.jit(prefill_step)
         # per-step serving telemetry (latency percentiles, slot utilization,
         # admissions) — read it back via stats()
         self.metrics = SchedulerMetrics(slots=slots)

@@ -45,7 +45,7 @@ def test_flash_attention_fwd(B, H, Hkv, S, hd, bq, bk, causal, window, dtype):
     k = jax.random.normal(ks[1], (B, Hkv, S, hd), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, S, hd), dtype)
     out, lse = flash_attention_fwd(
-        q, k, v, causal=causal, window=window, bq=bq, bk=bk, interpret=True
+        q, k, v, causal=causal, window=window, bq=bq, bk=bk
     )
     ref = attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(
@@ -63,7 +63,7 @@ def test_flash_attention_grad(causal, window):
     v = jax.random.normal(ks[2], (B, Hkv, S, hd))
 
     def f(q, k, v):
-        return (flash_attention(q, k, v, causal, window, 16, 16, True) ** 2).sum()
+        return (flash_attention(q, k, v, causal, window, 16, 16) ** 2).sum()
 
     def g(q, k, v):
         return (attention_ref(q, k, v, causal=causal, window=window) ** 2).sum()
@@ -106,7 +106,7 @@ def test_ssd_scan_fwd(b, S, H, P, N, chunk, dtype):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)))
     B = jax.random.normal(ks[3], (b, S, N), dtype)
     C = jax.random.normal(ks[4], (b, S, N), dtype)
-    y, fin = ssd_kernel(x, dt, A, B, C, chunk=chunk, interpret=True)
+    y, fin = ssd_kernel(x, dt, A, B, C, chunk=chunk)
     yr, finr = ssd_ref(x, dt, A, B, C)
     np.testing.assert_allclose(
         np.asarray(y, np.float32), np.asarray(yr, np.float32), **_tol(dtype)
@@ -124,7 +124,7 @@ def test_ssd_grad_matches_chunked():
     C = jax.random.normal(ks[4], (b, S, N))
 
     def f(x, dt, A, B, C):
-        return (ssd_op(x, dt, A, B, C, 8, True) ** 2).sum()
+        return (ssd_op(x, dt, A, B, C, 8) ** 2).sum()
 
     def g(x, dt, A, B, C):
         return (ssd_ref(x, dt, A, B, C)[0].astype(x.dtype) ** 2).sum()
@@ -154,7 +154,7 @@ def test_decode_attention(B, H, Hkv, S, hd, bk, pos, window, dtype):
     q = jax.random.normal(ks[0], (B, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, Hkv, S, hd), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, S, hd), dtype)
-    out = decode_kernel(q, k, v, jnp.int32(pos), window=window, bk=bk, interpret=True)
+    out = decode_kernel(q, k, v, jnp.int32(pos), window=window, bk=bk)
     ref = decode_attention_ref(q, k, v, jnp.int32(pos), window=window)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype)
