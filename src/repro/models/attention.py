@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.wstrace import spans
+
 from .common import apply_rope, dense_init
 from .sharding import shard
 
@@ -349,18 +351,22 @@ def _update_at(cache, new, pos_b):
     )(cache, new, pos_b)
 
 
-def _decode_qkv(x, p, cfg, cache: KVCache, pos_b):
-    """Shared decode prologue: project q/k/v for the new token, rope at the
-    per-slot positions, and splice k/v into the cache.  Returns
-    (q [B, 1, H, hd], updated KVCache)."""
+def _project_qkv(x, p, cfg, pos_b):
+    """Project q/k/v for the new token and rope them at the per-slot
+    positions: (q, k_new, v_new), each [B, 1, heads, hd]."""
     q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
     k_new = jnp.einsum("bsd,dhe->bshe", x, p["wk"])
     v_new = jnp.einsum("bsd,dhe->bshe", x, p["wv"])
     q = apply_rope(q, pos_b[:, None], cfg.rope_theta)
     k_new = apply_rope(k_new, pos_b[:, None], cfg.rope_theta)
-    kc = _update_at(cache.k, k_new, pos_b)
-    vc = _update_at(cache.v, v_new, pos_b)
-    return q, KVCache(kc, vc)
+    return q, k_new, v_new
+
+
+def _decode_qkv(x, p, cfg, cache: KVCache, pos_b):
+    """Shared decode prologue: :func:`_project_qkv`, then splice k/v into
+    the cache.  Returns (q [B, 1, H, hd], updated KVCache)."""
+    q, k_new, v_new = _project_qkv(x, p, cfg, pos_b)
+    return q, KVCache(_update_at(cache.k, k_new, pos_b), _update_at(cache.v, v_new, pos_b))
 
 
 def gqa_decode(x, p, cfg, cache: KVCache, pos, window):
@@ -416,24 +422,29 @@ def gqa_decode_ws(x, p, cfg, cache: KVCache, pos, *, schedule="ws", bk=64,
     B = x.shape[0]
     hd = cfg.hd
     H = p["wq"].shape[1]
-    pos_b = broadcast_pos(pos, B)
-    q, new_cache = _decode_qkv(x, p, cfg, cache, pos_b)
-
-    if isinstance(pos_b, jax.core.Tracer):
-        lengths = pos_b.astype(jnp.int32) + 1
-    else:
-        lengths = np.asarray(jax.device_get(pos_b)).astype(np.int64) + 1
+    with jax.named_scope(spans.WS_PUT):
+        pos_b = broadcast_pos(pos, B)
+        if isinstance(pos_b, jax.core.Tracer):
+            lengths = pos_b.astype(jnp.int32) + 1
+        else:
+            lengths = np.asarray(jax.device_get(pos_b)).astype(np.int64) + 1
+    with jax.named_scope(spans.DENSE):
+        q, k_new, v_new = _project_qkv(x, p, cfg, pos_b)
+        q = q.reshape(B, H, hd)
+    with jax.named_scope(spans.KV_LAYOUT):
+        new_cache = KVCache(_update_at(cache.k, k_new, pos_b),
+                            _update_at(cache.v, v_new, pos_b))
+        kt = new_cache.k.transpose(0, 2, 1, 3)  # [B, S, Hkv, hd] -> [B, Hkv, S, hd]
+        vt = new_cache.v.transpose(0, 2, 1, 3)
     o = ragged_decode_attention(
-        q.reshape(B, H, hd),
-        new_cache.k.transpose(0, 2, 1, 3),  # [B, S, Hkv, hd] -> [B, Hkv, S, hd]
-        new_cache.v.transpose(0, 2, 1, 3),
-        lengths,
+        q, kt, vt, lengths,
         schedule=schedule,
         n_programs=n_programs,
         bk=bk,
     )
-    o = o.reshape(B, 1, H, hd).astype(x.dtype)
-    return jnp.einsum("bshe,hed->bsd", o, p["wo"]), new_cache
+    with jax.named_scope(spans.DENSE):
+        o = o.reshape(B, 1, H, hd).astype(x.dtype)
+        return jnp.einsum("bshe,hed->bsd", o, p["wo"]), new_cache
 
 
 # ---------------------------------------------------------------------------

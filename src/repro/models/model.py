@@ -20,6 +20,8 @@ from typing import Any, Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.wstrace import spans
+
 from . import attention as attn
 from . import moe as moe_mod
 from . import ssm as ssm_mod
@@ -382,30 +384,35 @@ def decode_step_ws(
     a decode step run on the scheduler, eager or compiled.
     """
     assert ws_decode_supported(cfg), cfg.name
-    x = _embed(params, cfg, tokens)
     s = tf._res_scale(cfg)
     kv = caches.kv
-    h = x
+    with jax.named_scope(spans.DENSE):
+        h = _embed(params, cfg, tokens)
     for idx in range(cfg.n_layers):
-        p = jax.tree_util.tree_map(lambda a: a[idx], params["layers"])
-        cache = _layer_cache(kv, idx)
-        hn = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+        with jax.named_scope(spans.DENSE):
+            p = jax.tree_util.tree_map(lambda a: a[idx], params["layers"])
+            hn = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+        with jax.named_scope(spans.KV_LAYOUT):
+            cache = _layer_cache(kv, idx)
         a, new_cache = attn.gqa_decode_ws(
             hn, p["attn"], cfg, cache, pos,
             schedule=schedule, bk=bk, n_programs=n_programs,
         )
-        h = h + s * a
-        hn = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-        if "moe" in p:
-            m, _ = moe_mod.moe_ffn_dispatch(hn, p["moe"], cfg)
-        else:
-            m = swiglu(hn, p["mlp"]["wg"], p["mlp"]["wu"], p["mlp"]["wd"])
-        h = h + s * m
-        kv = _set_layer_cache(kv, new_cache, idx)
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", h, _unembed_matrix(params, cfg))[:, 0]
-    logits = _mask_pad_vocab(logits.astype(jnp.float32), cfg)
-    return shard(logits, "dp", "tp"), Caches(kv=kv)
+        with jax.named_scope(spans.DENSE):
+            h = h + s * a
+            hn = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
+            if "moe" in p:
+                m, _ = moe_mod.moe_ffn_dispatch(hn, p["moe"], cfg)
+            else:
+                m = swiglu(hn, p["mlp"]["wg"], p["mlp"]["wu"], p["mlp"]["wd"])
+            h = h + s * m
+        with jax.named_scope(spans.KV_LAYOUT):
+            kv = _set_layer_cache(kv, new_cache, idx)
+    with jax.named_scope(spans.DENSE):
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", h, _unembed_matrix(params, cfg))[:, 0]
+        logits = _mask_pad_vocab(logits.astype(jnp.float32), cfg)
+        return shard(logits, "dp", "tp"), Caches(kv=kv)
 
 
 def _cross_decode(x, p, cfg, cross: attn.KVCache):

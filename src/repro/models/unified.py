@@ -717,7 +717,7 @@ def decode_step_unified(
         state, execute, pure, tuple(outs),
         steal=steal, steal_policy=steal_policy, rounds=rounds,
         compress_runs=False, stage_open=stage_open,
-        trace=trace,
+        trace=trace, name="ws_unified",
     )
     if check:
         _check_drained(n_tasks, res)

@@ -259,7 +259,7 @@ def run_moe_grad_schedule(
         steal=steal, steal_policy=steal_policy, steal_run_cap=steal_run_cap,
         rounds=rounds, mult=mult,
         compress_runs=compress_runs, trace=trace,
-        trace_capacity=trace_capacity,
+        trace_capacity=trace_capacity, name="ws_expert_grad",
     )
 
 
@@ -300,5 +300,5 @@ def run_moe_schedule(
         steal=steal, steal_policy=steal_policy, steal_run_cap=steal_run_cap,
         rounds=rounds, mult=mult,
         compress_runs=compress_runs, trace=trace,
-        trace_capacity=trace_capacity, fault_plan=fault_plan,
+        trace_capacity=trace_capacity, fault_plan=fault_plan, name="ws_expert",
     )

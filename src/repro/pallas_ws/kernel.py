@@ -743,6 +743,7 @@ def launch_ws_grid(
     trace_remote: bool = False,
     fault_plan=None,
     smem_pure: Sequence[jax.Array] = (),
+    name: str = "ws_grid",
 ) -> WSRunResult:
     """Run the persistent WS grid with a family ``execute`` body.
 
@@ -802,6 +803,10 @@ def launch_ws_grid(
     given, the Graham default is extended by the maximum stall so stalled
     schedules still drain.  Cross-launch faults (storms, kills) live in
     :func:`repro.chaos.inject.run_with_faults`.
+
+    ``name`` names the ``pallas_call``, one per task family (``ws_decode``,
+    ``ws_flash``, ``ws_attention``, ``ws_expert``, ``ws_expert_grad``,
+    ``ws_unified``), so that a profile says which family ran.
     """
     assert steal_policy in STEAL_POLICIES, steal_policy
     P = state.n_programs
@@ -920,6 +925,7 @@ def launch_ws_grid(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret_mode(),
+        name=name,
     )(*mutable, *sched_in, *pure_arrays)
     n_live = N_SCHED_MUTABLE + len(outs_in)
     (head, local_head, taken, remaining, clock, work, steals, scanned,
@@ -1059,6 +1065,7 @@ def run_ws_schedule(
     trace: bool = False,
     trace_capacity: Optional[int] = None,
     fault_plan=None,
+    name: str = "ws_attention",
 ) -> WSRunResult:
     """Launch the attention megakernel over a prepared :class:`QueueState`.
 
@@ -1083,4 +1090,5 @@ def run_ws_schedule(
         rounds=rounds, mult=mult,
         compress_runs=compress_runs,
         trace=trace, trace_capacity=trace_capacity, fault_plan=fault_plan,
+        name=name,
     )

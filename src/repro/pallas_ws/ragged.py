@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.wstrace import spans
+
 from .kernel import WSRunResult, run_ws_schedule
 from .queues import make_queue_state, make_queue_state_jax, owner_queue_candidates, queue_costs
 from .tasks import (
@@ -144,7 +146,7 @@ def ragged_flash_attention(
         causal=causal, bq=bq, bk=bk,
         steal=(schedule == "ws"), steal_policy=steal_policy,
         steal_run_cap=steal_run_cap if schedule == "ws" else 1,
-        trace=trace,
+        trace=trace, name="ws_flash",
     )
     _check_drained(state, res)
     div = multiplicity_divisor(tasks, res.mult, (B, H, qp.shape[2]))
@@ -237,48 +239,51 @@ def ragged_decode_attention(
     bk = min(bk, max(1, S))
     steal = schedule == "ws"
     traced = isinstance(lengths, jax.core.Tracer)
+    if traced and return_stats:
+        raise ValueError("return_stats needs concrete telemetry; call eagerly")
+    if traced and trace:
+        raise ValueError("trace needs concrete event rings; call eagerly")
 
-    if traced:
-        if return_stats:
-            raise ValueError("return_stats needs concrete telemetry; call eagerly")
-        if trace:
-            raise ValueError("trace needs concrete event rings; call eagerly")
-        n_queues = n_programs  # partition="batch": queue = b % n_programs
-        records, live = emit_decode_tasks_jax(lengths, H, bk)
-        cand, cand_live = owner_queue_candidates(records, live, n_queues)
-        state = make_queue_state_jax(cand, cand_live, n_programs, n_tasks=B * H)
-        rounds = decode_rounds_bound(
-            B, H, S, bk, n_queues, n_programs, steal,
-            steal_run_cap=steal_run_cap if steal else 1,
+    with jax.named_scope(spans.WS_PUT):
+        if traced:
+            n_queues = n_programs  # partition="batch": queue = b % n_programs
+            records, live = emit_decode_tasks_jax(lengths, H, bk)
+            cand, cand_live = owner_queue_candidates(records, live, n_queues)
+            state = make_queue_state_jax(cand, cand_live, n_programs, n_tasks=B * H)
+            rounds = decode_rounds_bound(
+                B, H, S, bk, n_queues, n_programs, steal,
+                steal_run_cap=steal_run_cap if steal else 1,
+            )
+            tasks = None
+        else:
+            lengths = np.asarray(lengths, dtype=np.int64)
+            assert lengths.shape == (B,) and lengths.max(initial=0) <= S
+            tasks = emit_decode_tasks(lengths, H, bk)
+            state = make_queue_state(tasks, n_programs, partition=partition)
+            rounds = None
+        # one query row per tile: float32 keeps that row aligned to the chip's
+        # (1, 128) tiling (a packed bf16 row is half a tile); the tile body
+        # computes in float32 either way
+        q4 = q.astype(jnp.float32)[:, :, None, :]
+        kp = _pad_to(k, 2, bk)
+        vp = _pad_to(v, 2, bk)
+    with jax.named_scope(spans.WS_KERNEL):
+        res = run_ws_schedule(
+            state, q4, kp, vp,
+            causal=False, bq=1, bk=bk,
+            steal=steal, steal_policy=steal_policy,
+            steal_run_cap=steal_run_cap if steal else 1, rounds=rounds,
+            trace=trace, name="ws_decode",
         )
-        tasks = None
-    else:
-        lengths = np.asarray(lengths, dtype=np.int64)
-        assert lengths.shape == (B,) and lengths.max(initial=0) <= S
-        tasks = emit_decode_tasks(lengths, H, bk)
-        state = make_queue_state(tasks, n_programs, partition=partition)
-        rounds = None
-    # one query row per tile: float32 keeps that row aligned to the chip's
-    # (1, 128) tiling (a packed bf16 row is half a tile); the tile body
-    # computes in float32 either way
-    q4 = q.astype(jnp.float32)[:, :, None, :]
-    kp = _pad_to(k, 2, bk)
-    vp = _pad_to(v, 2, bk)
-    res = run_ws_schedule(
-        state, q4, kp, vp,
-        causal=False, bq=1, bk=bk,
-        steal=steal, steal_policy=steal_policy,
-        steal_run_cap=steal_run_cap if steal else 1, rounds=rounds,
-        trace=trace,
-    )
-    if traced:
-        # tid = b·H + h is static: the divisor is just the reshaped
-        # multiplicity buffer (dead slots: mult 0 -> divisor 1, output 0)
-        div = jnp.maximum(res.mult.reshape(B, H), 1).astype(jnp.float32)
-        return (res.out / div[:, :, None, None])[:, :, 0].astype(q.dtype)
-    _check_drained(state, res)
-    div = multiplicity_divisor(tasks, res.mult, (B, H, 1))
-    out = (res.out / jnp.asarray(div)[..., None])[:, :, 0].astype(q.dtype)
+    with jax.named_scope(spans.WS_PUT):
+        if traced:
+            # tid = b·H + h is static: the divisor is just the reshaped
+            # multiplicity buffer (dead slots: mult 0 -> divisor 1, output 0)
+            div = jnp.maximum(res.mult.reshape(B, H), 1).astype(jnp.float32)
+            return (res.out / div[:, :, None, None])[:, :, 0].astype(q.dtype)
+        _check_drained(state, res)
+        div = multiplicity_divisor(tasks, res.mult, (B, H, 1))
+        out = (res.out / jnp.asarray(div)[..., None])[:, :, 0].astype(q.dtype)
     if return_stats:
         return out, RaggedStats.from_run(schedule, state, res, steal_policy)
     return out

@@ -50,6 +50,7 @@ from repro.models import (
     ws_decode_supported,
 )
 from repro.models.unified import require_interpreter
+from repro.wstrace import spans
 from repro.wstrace.metrics import SchedulerMetrics
 
 
@@ -163,6 +164,7 @@ class ContinuousBatcher:
         # per-step serving telemetry (latency percentiles, slot utilization,
         # admissions) — read it back via stats()
         self.metrics = SchedulerMetrics(slots=slots)
+        spans.install_gc_spans()
         # Watchdog (unified mode): a step whose logits come back non-finite
         # is discarded and redone on the split path this very step; a step
         # that blows `step_deadline_s` routes the next `watchdog_cooldown`
@@ -225,10 +227,15 @@ class ContinuousBatcher:
             self.budget[slot] = req.max_new
             self.metrics.record_admission()
             return True
-        batch = {"tokens": jnp.asarray(req.tokens, jnp.int32)[None, :]}
-        logits, c1 = self._prefill(self.params, batch)
-        self._splice_slot(slot, c1)
-        first = int(self._select(np.asarray(logits[:1]))[0])
+        with jax.profiler.TraceAnnotation(spans.ENGINE_ADMIT, rid=int(req.rid),
+                                          slot=slot, prompt_len=len(req.tokens)):
+            with jax.profiler.TraceAnnotation(spans.ADMIT_PREFILL):
+                batch = {"tokens": jnp.asarray(req.tokens, jnp.int32)[None, :]}
+                logits, c1 = self._prefill(self.params, batch)
+            with jax.profiler.TraceAnnotation(spans.ADMIT_SPLICE):
+                self._splice_slot(slot, c1)
+            with jax.profiler.TraceAnnotation(spans.ADMIT_FIRST_TOKEN):
+                first = int(self._select(np.asarray(logits[:1]))[0])
         req.out.append(first)
         self.live[slot] = req
         self.pos[slot] = len(req.tokens)
@@ -240,32 +247,43 @@ class ContinuousBatcher:
     def step(self) -> List[Request]:
         if not any(r is not None for r in self.live):
             return []
-        if self.unified:
-            return self._step_unified()
+        with jax.profiler.TraceAnnotation(spans.ENGINE_STEP,
+                                          step=len(self.metrics.step_latency_s),
+                                          live=self.n_live):
+            if self.unified:
+                return self._step_unified()
+            return self._step_split()
+
+    def _step_split(self) -> List[Request]:
         n_live = self.n_live
         t0 = time.perf_counter()
-        tokens = np.zeros((self.B, 1), dtype=np.int32)
-        for i, r in enumerate(self.live):
-            if r is not None:
-                tokens[i, 0] = r.out[-1]
-        # per-slot decode positions (heterogeneous sequence lengths)
-        logits, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(tokens), jnp.asarray(self.pos)
-        )
-        done = []
-        nxt = self._select(np.asarray(logits))  # syncs the device step
+        with jax.profiler.TraceAnnotation(spans.STEP_INPUTS):
+            tokens = np.zeros((self.B, 1), dtype=np.int32)
+            for i, r in enumerate(self.live):
+                if r is not None:
+                    tokens[i, 0] = r.out[-1]
+            # per-slot decode positions (heterogeneous sequence lengths)
+            tok, pos = jnp.asarray(tokens), jnp.asarray(self.pos)
+        with jax.profiler.TraceAnnotation(spans.STEP_DISPATCH):
+            logits, self.caches = self._decode(self.params, self.caches, tok, pos)
+        with jax.profiler.TraceAnnotation(spans.STEP_SYNC):
+            lg = np.asarray(logits)
+        with jax.profiler.TraceAnnotation(spans.STEP_SAMPLE):
+            nxt = self._select(lg)
         self.metrics.record_step(time.perf_counter() - t0, n_live)
-        for i, r in enumerate(self.live):
-            if r is None:
-                continue
-            r.out.append(int(nxt[i]))
-            self.pos[i] += 1
-            self.budget[i] -= 1
-            if self.budget[i] <= 0 or self.pos[i] >= self.cap - 1:
-                done.append(r)
-                self.live[i] = None
-        if done:
-            self.metrics.record_completion(len(done))
+        done = []
+        with jax.profiler.TraceAnnotation(spans.STEP_COMMIT):
+            for i, r in enumerate(self.live):
+                if r is None:
+                    continue
+                r.out.append(int(nxt[i]))
+                self.pos[i] += 1
+                self.budget[i] -= 1
+                if self.budget[i] <= 0 or self.pos[i] >= self.cap - 1:
+                    done.append(r)
+                    self.live[i] = None
+            if done:
+                self.metrics.record_completion(len(done))
         return done
 
     def _degrade(self, step_idx: int, kind: str, detail: str) -> None:
@@ -595,6 +613,10 @@ class WorkStealingFrontend:
         queues (honoring each admit's verdict), then step every busy
         batcher.  Returns True if anything happened — an admission, a
         rejection, or a live engine step."""
+        with jax.profiler.TraceAnnotation(spans.FRONTEND_ITERATION):
+            return self._iterate()
+
+    def _iterate(self) -> bool:
         worked = False
         it = self._iter
         self._iter += 1
