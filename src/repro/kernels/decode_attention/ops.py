@@ -21,10 +21,10 @@ def ragged_decode_attention(
 ):
     """Decode attention over ragged KV caches (per-sequence lengths).
 
-    ``schedule="ws"`` dispatches one task per live (batch, head) through the
-    fence-free work-stealing megakernel (:mod:`repro.pallas_ws`) so long
-    caches don't serialize one grid program; ``schedule="static"`` is the
-    no-steal baseline.
+    ``schedule="ws"`` dispatches one task per live (batch, KV head), its
+    query heads as the tile's rows, through the fence-free work-stealing
+    megakernel (:mod:`repro.pallas_ws`) so long caches don't serialize one
+    grid program; ``schedule="static"`` is the no-steal baseline.
     """
     from repro.pallas_ws.ragged import ragged_decode_attention as _impl
 

@@ -11,9 +11,10 @@ slot, optionally one folded-in prefill prompt — into a SINGLE persistent-grid
 `launch_ws_grid` launch mixing all three task families of
 `repro.pallas_ws.tasks`:
 
-* **attention** — decode tiles (one `(b, h)` query row sweeping its live kv
-  range) and prefill flash tiles (causal `(h, q-block)` tiles), exactly the
-  records `emit_decode_tasks` / `emit_flash_tasks` produce;
+* **attention** — decode tiles (one `(b, kv head)` tile whose q block holds
+  the G query heads sharing that kv head, sweeping its live kv range once)
+  and prefill flash tiles (causal `(h, q-block)` tiles), exactly the records
+  `emit_decode_tasks` / `emit_flash_tasks` produce;
 * **expert** — shared-pool expert-FFN tiles per MoE layer and segment, with
   the *routing gathered in-kernel* from buffers a glue phase wrote;
 * **step-glue** — the inter-stage phases (`GLUE_*` codes below): embed,
@@ -61,7 +62,13 @@ from jax.experimental import pallas as pl
 from repro.interpret import interpret_mode
 from repro.pallas_ws.kernel import WSRunResult, _attention_execute, launch_ws_grid
 from repro.pallas_ws.queues import QueueState, make_staged_queue_state
-from repro.pallas_ws.ragged import _pad_to
+from repro.pallas_ws.ragged import (
+    DECODE_BK,
+    _pad_to,
+    decode_q_block,
+    decode_q_rows,
+    decode_q_unblock,
+)
 from repro.pallas_ws.tasks import (
     BOTTOM,
     F_COST,
@@ -238,6 +245,9 @@ def decode_step_unified(
     carries the prompt's last-token logits plus its spliced [L, 1, cap, ...]
     k/v cache for the engine to install.
 
+    The decode tiles sweep kv blocks of ``DECODE_BK``, as the split path's
+    decode does; ``bq``/``bk`` size the folded prompt's flash tiles.
+
     ``trace=True`` records the per-extraction event rings — a single ring
     stream containing every family's ops, the launch-count witness the
     acceptance criteria ask for.
@@ -263,7 +273,9 @@ def decode_step_unified(
     lengths = pos_h + 1
 
     # -- decode tile geometry: exactly what ragged_decode_attention schedules
-    bk_d = min(bk, max(1, cap))
+    # (one tile per (slot, kv head), its G query heads as q rows)
+    G, G_pad = decode_q_rows(H, Hkv)
+    bk_d = min(DECODE_BK, max(1, cap))
     S_pad = -(-cap // bk_d) * bk_d
 
     has_prefill = prefill_tokens is not None
@@ -292,7 +304,7 @@ def decode_step_unified(
     # they are independent of queue/stage placement.
     n_glue = 2 + L * (2 + int(is_moe))
     dec_att_base = n_glue
-    pre_att_base = dec_att_base + L * B * H
+    pre_att_base = dec_att_base + L * B * Hkv
     exp_dec_base = pre_att_base + L * n_flash_l
     exp_pre_base = exp_dec_base + L * pool_dec
     n_tasks = exp_pre_base + L * pool_pre
@@ -314,8 +326,8 @@ def decode_step_unified(
         return t
 
     def dec_tiles(layer):
-        tasks = emit_decode_tasks(lengths, H, bk_d)
-        base = dec_att_base + layer * B * H
+        tasks = emit_decode_tasks(lengths, Hkv, bk_d, q_rows=G)
+        base = dec_att_base + layer * B * Hkv
         return [dataclasses.replace(t, tid=base + t.tid) for t in tasks]
 
     def flash_tiles(layer):
@@ -357,7 +369,7 @@ def decode_step_unified(
     # memo key: everything the assembly reads — the length vector, the
     # pending-admission shape, and the static geometry knobs
     cache_key = (
-        tuple(int(x) for x in lengths), Lp, B, L, H, bk_d, bq_p, bk_p,
+        tuple(int(x) for x in lengths), Lp, B, L, H, Hkv, bk_d, bq_p, bk_p,
         bt, n_programs, bool(is_moe), E, top_k, pool_dec, pool_pre,
     )
     cached = _STAGE_CACHE.get(cache_key)
@@ -384,10 +396,10 @@ def decode_step_unified(
     buf("kc", jnp.asarray(caches.kv.k))
     buf("vc", jnp.asarray(caches.kv.v))
     buf("h", jnp.zeros((B, 1, d), dt))
-    buf("qd", jnp.zeros((B, H, 1, hd), dt))
+    buf("qd", jnp.zeros((B, Hkv, G_pad, hd), jnp.float32))
     buf("ktd", jnp.zeros((B, Hkv, S_pad, hd), dt))
     buf("vtd", jnp.zeros((B, Hkv, S_pad, hd), dt))
-    buf("attd", jnp.zeros((B, H, 1, hd), jnp.float32))
+    buf("attd", jnp.zeros((B, Hkv, G_pad, hd), jnp.float32))
     buf("logits", jnp.zeros((B, Vp), jnp.float32))
     if is_moe:
         buf("xfd", jnp.zeros((B, d), dt))
@@ -445,7 +457,7 @@ def decode_step_unified(
         def _decode_tile():
             _attention_execute(
                 rec, (o("qd"), o("ktd"), o("vtd")), o("attd"),
-                bq=1, bk=bk_d, causal=False, scale=hd**-0.5, g=H // Hkv,
+                bq=G_pad, bk=bk_d, causal=False, scale=hd**-0.5, g=1,
             )
 
         if has_prefill:
@@ -596,7 +608,7 @@ def decode_step_unified(
                 o("vc")[...] = jax.lax.dynamic_update_slice_in_dim(
                     vc_full, new_cache.v[None].astype(vc_full.dtype), lyr, 0
                 )
-                o("qd")[...] = q.reshape(B, H, hd)[:, :, None, :]
+                o("qd")[...] = decode_q_block(q.reshape(B, H, hd), Hkv)
                 o("ktd")[...] = _pad_to(
                     new_cache.k.transpose(0, 2, 1, 3), 2, bk_d
                 )
@@ -626,10 +638,10 @@ def decode_step_unified(
                 p_l = layer_params(lyr)
                 # decode: multiplicity-normalized attention combine
                 # (ragged_decode_attention's divisor), wo, mlp norm
-                mult_a = mult_ref[pl.ds(dec_att_base + lyr * (B * H), B * H)]
-                div = jnp.maximum(mult_a, 1).astype(jnp.float32).reshape(B, H, 1)
+                mult_a = mult_ref[pl.ds(dec_att_base + lyr * (B * Hkv), B * Hkv)]
+                div = jnp.maximum(mult_a, 1).astype(jnp.float32).reshape(B, Hkv)
                 att = o("attd")[...]
-                ob = (att / div[..., None])[:, :, 0].astype(dt)
+                ob = decode_q_unblock(att / div[:, :, None, None], H).astype(dt)
                 a = jnp.einsum(
                     "bshe,hed->bsd", ob.reshape(B, 1, H, hd), p_l["attn"]["wo"]
                 )
@@ -645,7 +657,7 @@ def decode_step_unified(
                     m = swiglu(hn2, p_l["mlp"]["wg"], p_l["mlp"]["wu"],
                                p_l["mlp"]["wd"])
                     o("h")[...] = h2 + s * m
-                o("attd")[...] = jnp.zeros((B, H, 1, hd), jnp.float32)
+                o("attd")[...] = jnp.zeros((B, Hkv, G_pad, hd), jnp.float32)
                 if has_prefill:
                     mult_f = mult_ref[
                         pl.ds(pre_att_base + lyr * n_flash_l, n_flash_l)

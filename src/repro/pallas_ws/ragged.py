@@ -32,6 +32,8 @@ from .tasks import (
 )
 
 SCHEDULES = ("ws", "static")
+_F32_SUBLANES = 8  # rows of one float32 (8, 128) tile on the chip
+DECODE_BK = 64  # kv positions a decode tile copies and sweeps at a time
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -58,11 +60,12 @@ class RaggedStats:
     extractions: int
     scan_per_extraction: float
     queue_loads: list
+    q_rows: int = 1  # query heads a tile carries (G on a grouped decode launch)
     trace: object = None  # WSTrace when the launch recorded event rings
 
     @classmethod
     def from_run(cls, schedule, state, res: WSRunResult,
-                 steal_policy: str = "cost") -> "RaggedStats":
+                 steal_policy: str = "cost", q_rows: int = 1) -> "RaggedStats":
         trace = None
         if res.events is not None:
             from repro.wstrace.trace import WSTrace
@@ -81,6 +84,7 @@ class RaggedStats:
             extractions=res.extractions,
             scan_per_extraction=round(res.scan_per_extraction, 3),
             queue_loads=[int(c) for c in queue_costs(state)],
+            q_rows=q_rows,
             trace=trace,
         )
 
@@ -156,13 +160,14 @@ def ragged_flash_attention(
     return out
 
 
-def emit_decode_tasks_jax(lengths, n_heads: int, bk: int):
+def emit_decode_tasks_jax(lengths, n_heads: int, bk: int, q_rows: int = 1):
     """Traced twin of :func:`repro.pallas_ws.tasks.emit_decode_tasks`: the
-    full static ``[B, H]`` candidate grid with live masks ``lengths > 0``
-    instead of a host loop that skips dead rows.  ``tid = b·H + h`` is
-    static, so the multiplicity buffer is provisioned at ``B·H`` and dead
-    slots simply stay 0.  Returns ``(records [B, H, TASK_WIDTH],
-    live [B, H])`` ready for :func:`owner_queue_candidates`.
+    full static ``[B, n_heads]`` candidate grid with live masks
+    ``lengths > 0`` instead of a host loop that skips dead rows.  ``tid =
+    b·n_heads + h`` is static, so the multiplicity buffer is provisioned at
+    ``B·n_heads`` and dead slots simply stay 0.  Returns ``(records [B,
+    n_heads, TASK_WIDTH], live [B, n_heads])`` ready for
+    :func:`owner_queue_candidates`.
     """
     ln = jnp.asarray(lengths).astype(jnp.int32)
     B = ln.shape[0]
@@ -177,7 +182,7 @@ def emit_decode_tasks_jax(lengths, n_heads: int, bk: int):
             jnp.broadcast_to(b_ids, shape),
             jnp.broadcast_to(h_ids, shape),
             jnp.zeros(shape, jnp.int32),            # q_start
-            jnp.ones(shape, jnp.int32),             # q_len
+            jnp.full(shape, q_rows, jnp.int32),     # q_len
             jnp.broadcast_to(ln[:, None], shape),   # kv_end
             b_ids * H + h_ids,                      # tid (static, unique)
             jnp.broadcast_to(cost[:, None], shape),
@@ -191,8 +196,9 @@ def emit_decode_tasks_jax(lengths, n_heads: int, bk: int):
 def decode_rounds_bound(B: int, n_heads: int, S: int, bk: int,
                         n_queues: int, n_programs: int, steal: bool,
                         steal_run_cap: int = 1) -> int:
-    """Static worst-case lockstep rounds for a traced decode launch (every
-    slot at full cache length ``S``) — the trace-time stand-in for
+    """Static worst-case lockstep rounds for a traced decode launch of
+    ``n_heads`` tiles per slot (one per KV head), every slot at full cache
+    length ``S`` — the trace-time stand-in for
     :func:`repro.pallas_ws.kernel.default_rounds` (cost unit: kv blocks).
 
     Stealing: Graham's ``ceil(total/P) + max_cost`` with no scan slack —
@@ -208,6 +214,33 @@ def decode_rounds_bound(B: int, n_heads: int, S: int, bk: int,
     return STATIC_COMPRESSED_ROUNDS
 
 
+def decode_q_rows(n_heads: int, n_kv_heads: int) -> tuple[int, int]:
+    """``(G, G_pad)``: the query heads one decode tile carries (those that
+    share its KV head) and the rows of its q block.  The float32 q block is
+    padded with zero rows to whole (8, 128) tiles; multi-head attention
+    (G = 1) keeps its single row."""
+    assert n_heads % n_kv_heads == 0, (n_heads, n_kv_heads)
+    G = n_heads // n_kv_heads
+    return G, (G if G == 1 else _cdiv(G, _F32_SUBLANES) * _F32_SUBLANES)
+
+
+def decode_q_block(q, n_kv_heads: int):
+    """q [B, H, hd] -> the float32 decode q block [B, Hkv, G_pad, hd]: row
+    g of block kh is query head ``kh·G + g`` (``ragged_decode_ref``'s head
+    order), pad rows are zero."""
+    B, H, hd = q.shape
+    G, G_pad = decode_q_rows(H, n_kv_heads)
+    qb = q.astype(jnp.float32).reshape(B, n_kv_heads, G, hd)
+    return _pad_to(qb, 2, G_pad)
+
+
+def decode_q_unblock(out, n_heads: int):
+    """Inverse of :func:`decode_q_block` on a tile output [B, Hkv, G_pad,
+    hd]: drop the pad rows, back to [B, H, hd]."""
+    B, Hkv, _, hd = out.shape
+    return out[:, :, : n_heads // Hkv].reshape(B, n_heads, hd)
+
+
 def ragged_decode_attention(
     q,
     k,
@@ -219,7 +252,7 @@ def ragged_decode_attention(
     steal_run_cap: int = 1,
     n_programs: int = 8,
     partition: str = "batch",
-    bk: int = 64,
+    bk: int = DECODE_BK,
     return_stats: bool = False,
     trace: bool = False,
 ):
@@ -227,15 +260,22 @@ def ragged_decode_attention(
     ``[0, lengths[b])`` of k, v [B, Hkv, S, hd].  Dead rows (length 0)
     return 0.
 
+    One tile per live (slot, KV head): its q block holds the G = H / Hkv
+    query heads that share the KV head (:func:`decode_q_block`), so each
+    K/V block is copied and swept once for all G rows; multi-head
+    attention is the G = 1 case.  The multiplicity divisor is per (slot,
+    KV head).
+
     Accepts traced ``lengths`` (the jitted serving decode): queue
-    construction switches to the fixed-shape traced Put — the full [B, H]
-    candidate grid live-masked by ``lengths > 0``, compacted on device —
-    with the static worst-case rounds bound, and telemetry
+    construction switches to the fixed-shape traced Put — the full
+    [B, Hkv] candidate grid live-masked by ``lengths > 0``, compacted on
+    device — with the static worst-case rounds bound, and telemetry
     (``return_stats``) stays eager-only.
     """
     assert schedule in SCHEDULES, schedule
     B, H, hd = q.shape
-    S = k.shape[2]
+    Hkv, S = k.shape[1], k.shape[2]
+    G, G_pad = decode_q_rows(H, Hkv)
     bk = min(bk, max(1, S))
     steal = schedule == "ws"
     traced = isinstance(lengths, jax.core.Tracer)
@@ -247,45 +287,48 @@ def ragged_decode_attention(
     with jax.named_scope(spans.WS_PUT):
         if traced:
             n_queues = n_programs  # partition="batch": queue = b % n_programs
-            records, live = emit_decode_tasks_jax(lengths, H, bk)
+            records, live = emit_decode_tasks_jax(lengths, Hkv, bk, q_rows=G)
             cand, cand_live = owner_queue_candidates(records, live, n_queues)
-            state = make_queue_state_jax(cand, cand_live, n_programs, n_tasks=B * H)
+            state = make_queue_state_jax(cand, cand_live, n_programs, n_tasks=B * Hkv)
             rounds = decode_rounds_bound(
-                B, H, S, bk, n_queues, n_programs, steal,
+                B, Hkv, S, bk, n_queues, n_programs, steal,
                 steal_run_cap=steal_run_cap if steal else 1,
             )
             tasks = None
         else:
             lengths = np.asarray(lengths, dtype=np.int64)
             assert lengths.shape == (B,) and lengths.max(initial=0) <= S
-            tasks = emit_decode_tasks(lengths, H, bk)
+            tasks = emit_decode_tasks(lengths, Hkv, bk, q_rows=G)
             state = make_queue_state(tasks, n_programs, partition=partition)
             rounds = None
-        # one query row per tile: float32 keeps that row aligned to the chip's
-        # (1, 128) tiling (a packed bf16 row is half a tile); the tile body
-        # computes in float32 either way
-        q4 = q.astype(jnp.float32)[:, :, None, :]
+        # float32 q rows keep the block aligned to the chip's (8, 128) tiling
+        # (a packed bf16 row is half a tile); the tile body computes in
+        # float32 either way
+        qb = decode_q_block(q, Hkv)
         kp = _pad_to(k, 2, bk)
         vp = _pad_to(v, 2, bk)
     with jax.named_scope(spans.WS_KERNEL):
         res = run_ws_schedule(
-            state, q4, kp, vp,
-            causal=False, bq=1, bk=bk,
+            state, qb, kp, vp,
+            causal=False, bq=G_pad, bk=bk,
             steal=steal, steal_policy=steal_policy,
             steal_run_cap=steal_run_cap if steal else 1, rounds=rounds,
             trace=trace, name="ws_decode",
         )
     with jax.named_scope(spans.WS_PUT):
         if traced:
-            # tid = b·H + h is static: the divisor is just the reshaped
+            # tid = b·Hkv + kh is static: the divisor is just the reshaped
             # multiplicity buffer (dead slots: mult 0 -> divisor 1, output 0)
-            div = jnp.maximum(res.mult.reshape(B, H), 1).astype(jnp.float32)
-            return (res.out / div[:, :, None, None])[:, :, 0].astype(q.dtype)
+            div = jnp.maximum(res.mult.reshape(B, Hkv), 1).astype(jnp.float32)
+            out = res.out / div[:, :, None, None]
+            return decode_q_unblock(out, H).astype(q.dtype)
         _check_drained(state, res)
-        div = multiplicity_divisor(tasks, res.mult, (B, H, 1))
-        out = (res.out / jnp.asarray(div)[..., None])[:, :, 0].astype(q.dtype)
+        div = multiplicity_divisor(tasks, res.mult, (B, Hkv, G_pad))
+        out = res.out / jnp.asarray(div)[..., None]
+        out = decode_q_unblock(out, H).astype(q.dtype)
     if return_stats:
-        return out, RaggedStats.from_run(schedule, state, res, steal_policy)
+        return out, RaggedStats.from_run(schedule, state, res, steal_policy,
+                                         q_rows=G)
     return out
 
 

@@ -46,7 +46,7 @@ F_COST = 7    # tile-slots this task occupies (the lockstep clock cost model)
 
 # -- attention family operands (fields 1-5) ---------------------------------
 F_B = 1       # batch row
-F_H = 2       # query head
+F_H = 2       # head of the q block: query head (flash), KV head (decode)
 F_QS = 3      # first q row of the tile
 F_QL = 4      # number of live q rows (< bq on a ragged tail tile)
 F_KV = 5      # kv end, exclusive (== sequence length)
@@ -249,8 +249,13 @@ def emit_flash_tasks(lengths, n_heads: int, bq: int, bk: int, causal: bool = Tru
     return tasks
 
 
-def emit_decode_tasks(lengths, n_heads: int, bk: int):
-    """One task per live (b, h): a single query row sweeping kv [0, len)."""
+def emit_decode_tasks(lengths, n_heads: int, bk: int, q_rows: int = 1):
+    """One task per live (b, h): ``q_rows`` query rows sweeping kv [0, len).
+
+    The decode front-end emits one task per (slot, KV head) — ``n_heads`` is
+    Hkv and ``q_rows`` the G query heads that share each KV head, so a
+    K/V block is read once for all G rows; multi-head attention is G = 1.
+    """
     tasks = []
     tid = 0
     for b, ln in enumerate(np.asarray(lengths, dtype=np.int64)):
@@ -260,7 +265,8 @@ def emit_decode_tasks(lengths, n_heads: int, bk: int):
         for h in range(n_heads):
             tasks.append(
                 TileTask(
-                    OP_DECODE_TILE, b, h, 0, 1, ln, tid, max(1, _cdiv(ln, bk))
+                    OP_DECODE_TILE, b, h, 0, q_rows, ln, tid,
+                    max(1, _cdiv(ln, bk)),
                 )
             )
             tid += 1

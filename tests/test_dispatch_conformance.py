@@ -414,12 +414,14 @@ def check_decode_layout_conformance(draw_int):
     bk = (4, 8)[draw_int(0, 1)]
     nq = draw_int(1, 4)
     lengths = np.asarray([draw_int(0, 32) for _ in range(B)], dtype=np.int64)
+    # query rows per tile: G query heads share each (KV) head's tile
+    q_rows = draw_int(1, 4)
 
-    tasks = emit_decode_tasks(lengths, H, bk)
+    tasks = emit_decode_tasks(lengths, H, bk, q_rows=q_rows)
     sh = make_queue_state(tasks, P, n_queues=nq, partition="batch")
 
     records, live = jax.jit(
-        lambda ln: emit_decode_tasks_jax(ln, H, bk)
+        lambda ln: emit_decode_tasks_jax(ln, H, bk, q_rows=q_rows)
     )(jnp.asarray(lengths))
     cand, cand_live = owner_queue_candidates(records, live, nq)
     sj = make_queue_state_jax(cand, cand_live, P, n_tasks=B * H)

@@ -5,7 +5,9 @@ Nothing runs here: each test lowers a jitted program for one chip of a
 device attached, and compiles it — Mosaic refuses what interpret mode
 cannot catch (unaligned slices, vector access to SMEM, more fast memory
 than a kernel may use).  Shapes are the real widths: llama3.2-3b's heads
-and caches, and the expert tile at the width the chip check names.
+and caches (3 query heads per KV head, so a decode tile's q block carries
+pad rows), the serving benchmark's Mistral-7B attention (4 per KV head),
+and the expert tile at the width the chip check names.
 
 The code under test picks interpret mode from the backend, which is the
 CPU here, so each test switches the launch to the compiled kernel itself.
@@ -28,6 +30,8 @@ from repro.configs import get_config  # noqa: E402
 
 # llama3.2-3b attention at full width: 8 slots of a 2048-token cache
 B, H, HKV, S, HD = 8, 24, 8, 2048, 128
+# Mistral-7B-v0.3 attention as the serving benchmark runs it: 4 slots
+MISTRAL_B, MISTRAL_H = 4, 32
 # the expert width the chip check runs (deepseek-v2-236b routing shape,
 # d_model cut to 512 so one whole expert's float32 weights fit VMEM)
 E, TOP_K, T, D_EXPERT, F_EXPERT, BT = 160, 6, 64, 512, 1536, 8
@@ -75,15 +79,25 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-def test_decode_megakernel_compiles_at_llama_width(one_chip, compiled_kernels):
+def _compile_decode_megakernel(sharding, slots, heads):
     from repro.pallas_ws.ragged import ragged_decode_attention
 
     fn = jax.jit(lambda q, k, v, ln: ragged_decode_attention(q, k, v, ln))
-    kv = _sds(one_chip, (B, HKV, S, HD), jnp.bfloat16)
-    compiled = fn.lower(
-        _sds(one_chip, (B, H, HD), jnp.bfloat16), kv, kv,
-        _sds(one_chip, (B,), jnp.int32),
+    kv = _sds(sharding, (slots, HKV, S, HD), jnp.bfloat16)
+    return fn.lower(
+        _sds(sharding, (slots, heads, HD), jnp.bfloat16), kv, kv,
+        _sds(sharding, (slots,), jnp.int32),
     ).compile()
+
+
+def test_decode_megakernel_compiles_at_llama_width(one_chip, compiled_kernels):
+    compiled = _compile_decode_megakernel(one_chip, B, H)
+    assert _custom_calls(compiled) >= 1
+
+
+def test_decode_megakernel_compiles_at_mistral_width(one_chip,
+                                                     compiled_kernels):
+    compiled = _compile_decode_megakernel(one_chip, MISTRAL_B, MISTRAL_H)
     assert _custom_calls(compiled) >= 1
 
 
