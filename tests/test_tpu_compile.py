@@ -7,7 +7,8 @@ cannot catch (unaligned slices, vector access to SMEM, more fast memory
 than a kernel may use).  Shapes are the real widths: llama3.2-3b's heads
 and caches (3 query heads per KV head, so a decode tile's q block carries
 pad rows), the serving benchmark's Mistral-7B attention (4 per KV head),
-and the expert tile at the width the chip check names.
+its Mistral-NeMo attention at 6 slots of an 8192-token cache (an 896-round
+grid a layer), and the expert tile at the width the chip check names.
 
 The code under test picks interpret mode from the backend, which is the
 CPU here, so each test switches the launch to the compiled kernel itself.
@@ -32,6 +33,9 @@ from repro.configs import get_config  # noqa: E402
 B, H, HKV, S, HD = 8, 24, 8, 2048, 128
 # Mistral-7B-v0.3 attention as the serving benchmark runs it: 4 slots
 MISTRAL_B, MISTRAL_H = 4, 32
+# Mistral-NeMo-12B attention as its long-document cell runs it: 6 slots of
+# an 8192-token cache, 32 query heads over 8 KV heads of 128
+NEMO_B, NEMO_H, NEMO_S = 6, 32, 8192
 # the expert width the chip check runs (deepseek-v2-236b routing shape,
 # d_model cut to 512 so one whole expert's float32 weights fit VMEM)
 E, TOP_K, T, D_EXPERT, F_EXPERT, BT = 160, 6, 64, 512, 1536, 8
@@ -79,11 +83,11 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-def _compile_decode_megakernel(sharding, slots, heads):
+def _compile_decode_megakernel(sharding, slots, heads, capacity=S):
     from repro.pallas_ws.ragged import ragged_decode_attention
 
     fn = jax.jit(lambda q, k, v, ln: ragged_decode_attention(q, k, v, ln))
-    kv = _sds(sharding, (slots, HKV, S, HD), jnp.bfloat16)
+    kv = _sds(sharding, (slots, HKV, capacity, HD), jnp.bfloat16)
     return fn.lower(
         _sds(sharding, (slots, heads, HD), jnp.bfloat16), kv, kv,
         _sds(sharding, (slots,), jnp.int32),
@@ -98,6 +102,15 @@ def test_decode_megakernel_compiles_at_llama_width(one_chip, compiled_kernels):
 def test_decode_megakernel_compiles_at_mistral_width(one_chip,
                                                      compiled_kernels):
     compiled = _compile_decode_megakernel(one_chip, MISTRAL_B, MISTRAL_H)
+    assert _custom_calls(compiled) >= 1
+
+
+def test_decode_megakernel_compiles_at_nemo_long_context(one_chip,
+                                                         compiled_kernels):
+    from repro.pallas_ws.ragged import decode_rounds_bound
+
+    assert decode_rounds_bound(NEMO_B, HKV, NEMO_S, 64, 8, 8, True) == 896
+    compiled = _compile_decode_megakernel(one_chip, NEMO_B, NEMO_H, NEMO_S)
     assert _custom_calls(compiled) >= 1
 
 
