@@ -61,9 +61,10 @@ CHECK_STEPS = (1, 48)
 LOGIT_RTOL = 0.1
 # Kernel-alone tolerance, absolute, on attention outputs of unit-variance
 # data.  Kernel and reference see the same bf16 values in float32; they
-# differ in accumulation order (64-position online-softmax blocks against
-# one softmax) and in the MXU's float32 precision: at default precision a
-# float32 product may be taken in one bf16 pass, 2^-8 relative per term.
+# differ in accumulation order (online-softmax blocks of ``decode_block``
+# positions against one softmax) and in the MXU's float32 precision: at
+# default precision a float32 product may be taken in one bf16 pass, 2^-8
+# relative per term.
 KERNEL_ATOL = 2e-2
 KERNEL_LENGTHS = (2048, 1057, 129, 1)
 

@@ -16,7 +16,7 @@ def decode_attention(q, k, v, pos, *, window: int = 0, bk: int = 512):
 
 
 def ragged_decode_attention(
-    q, k, v, lengths, *, schedule="ws", n_programs=8, bk=64,
+    q, k, v, lengths, *, schedule="ws", n_programs=8, bk=None,
     return_stats=False,
 ):
     """Decode attention over ragged KV caches (per-sequence lengths).

@@ -403,7 +403,7 @@ def gqa_decode(x, p, cfg, cache: KVCache, pos, window):
     return jnp.einsum("bshe,hed->bsd", o, p["wo"]), new_cache
 
 
-def gqa_decode_ws(x, p, cfg, cache: KVCache, pos, *, schedule="ws", bk=64,
+def gqa_decode_ws(x, p, cfg, cache: KVCache, pos, *, schedule="ws", bk=None,
                   n_programs=8):
     """One-token decode with the attention core routed through the
     device-resident work-stealing scheduler (repro.pallas_ws).
@@ -415,7 +415,9 @@ def gqa_decode_ws(x, p, cfg, cache: KVCache, pos, *, schedule="ws", bk=64,
     queue.  Full attention only (window == 0).  Traced positions (the
     jitted serving path) route through the fixed-shape traced Put inside
     ``ragged_decode_attention``; concrete positions keep the host-side Put
-    with its scheduling telemetry.
+    with its scheduling telemetry.  ``bk=None`` sweeps the cache in blocks
+    of ``repro.pallas_ws.ragged.decode_block`` (512 positions of a bf16
+    cache at hd 128).
     """
     from repro.pallas_ws.ragged import ragged_decode_attention
 

@@ -366,7 +366,7 @@ def ws_decode_supported(cfg) -> bool:
 
 def decode_step_ws(
     params, cfg, caches: Caches, tokens, pos,
-    *, schedule: str = "ws", bk: int = 64, n_programs: int = 8,
+    *, schedule: str = "ws", bk: int | None = None, n_programs: int = 8,
 ):
     """One decode step with attention routed through the device-resident
     work-stealing scheduler (repro.pallas_ws) instead of the dense masked

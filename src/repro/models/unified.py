@@ -63,8 +63,8 @@ from repro.interpret import interpret_mode
 from repro.pallas_ws.kernel import WSRunResult, _attention_execute, launch_ws_grid
 from repro.pallas_ws.queues import QueueState, make_staged_queue_state
 from repro.pallas_ws.ragged import (
-    DECODE_BK,
     _pad_to,
+    decode_block,
     decode_q_block,
     decode_q_rows,
     decode_q_unblock,
@@ -245,8 +245,9 @@ def decode_step_unified(
     carries the prompt's last-token logits plus its spliced [L, 1, cap, ...]
     k/v cache for the engine to install.
 
-    The decode tiles sweep kv blocks of ``DECODE_BK``, as the split path's
-    decode does; ``bq``/``bk`` size the folded prompt's flash tiles.
+    The decode tiles sweep kv blocks of :func:`decode_block` of the cache's
+    capacity, head size and dtype, as the split path's decode does;
+    ``bq``/``bk`` size only the folded prompt's flash tiles.
 
     ``trace=True`` records the per-extraction event rings — a single ring
     stream containing every family's ops, the launch-count witness the
@@ -275,7 +276,7 @@ def decode_step_unified(
     # -- decode tile geometry: exactly what ragged_decode_attention schedules
     # (one tile per (slot, kv head), its G query heads as q rows)
     G, G_pad = decode_q_rows(H, Hkv)
-    bk_d = min(DECODE_BK, max(1, cap))
+    bk_d = decode_block(cap, hd, caches.kv.k.dtype)
     S_pad = -(-cap // bk_d) * bk_d
 
     has_prefill = prefill_tokens is not None

@@ -33,11 +33,33 @@ from .tasks import (
 
 SCHEDULES = ("ws", "static")
 _F32_SUBLANES = 8  # rows of one float32 (8, 128) tile on the chip
-DECODE_BK = 64  # kv positions a decode tile copies and sweeps at a time
+DECODE_BLOCK_BYTES = 128 * 2**10  # one K (or V) block a decode tile copies
+_DECODE_BK_MIN = 64  # the fixed block length the rule falls back to
 
 
 def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def decode_block(S: int, hd: int, dtype) -> int:
+    """KV positions a decode tile copies and sweeps at a time, from the
+    cache's shape and dtype alone: one K (or V) block of about
+    ``DECODE_BLOCK_BYTES`` (512 positions at hd 128 bf16, 256 in float32,
+    1024 at hd 64 bf16), capped at the capacity ``S``.
+
+    Past the cap it is the largest power of two, at most that target, that
+    divides ``S``, so the cache is never padded to a whole number of
+    blocks; below 64 it falls back to 64 positions and pads as before.  A
+    longer block means fewer lockstep rounds (the clock counts blocks) and
+    fewer copies to wait on, at the price of a masked tail of under one
+    block per tile."""
+    target = max(1, DECODE_BLOCK_BYTES // (hd * jnp.dtype(dtype).itemsize))
+    if S <= target:
+        return max(1, S)
+    bk = 1 << (target.bit_length() - 1)
+    while S % bk:
+        bk //= 2
+    return bk if bk >= _DECODE_BK_MIN else _DECODE_BK_MIN
 
 
 @dataclass
@@ -252,7 +274,7 @@ def ragged_decode_attention(
     steal_run_cap: int = 1,
     n_programs: int = 8,
     partition: str = "batch",
-    bk: int = DECODE_BK,
+    bk: int | None = None,
     return_stats: bool = False,
     trace: bool = False,
 ):
@@ -264,7 +286,8 @@ def ragged_decode_attention(
     query heads that share the KV head (:func:`decode_q_block`), so each
     K/V block is copied and swept once for all G rows; multi-head
     attention is the G = 1 case.  The multiplicity divisor is per (slot,
-    KV head).
+    KV head).  ``bk=None`` sweeps blocks of :func:`decode_block` (512
+    positions of a bf16 cache at hd 128).
 
     Accepts traced ``lengths`` (the jitted serving decode): queue
     construction switches to the fixed-shape traced Put — the full
@@ -276,7 +299,7 @@ def ragged_decode_attention(
     B, H, hd = q.shape
     Hkv, S = k.shape[1], k.shape[2]
     G, G_pad = decode_q_rows(H, Hkv)
-    bk = min(bk, max(1, S))
+    bk = decode_block(S, hd, k.dtype) if bk is None else min(bk, max(1, S))
     steal = schedule == "ws"
     traced = isinstance(lengths, jax.core.Tracer)
     if traced and return_stats:

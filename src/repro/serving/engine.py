@@ -54,7 +54,7 @@ from repro.wstrace import spans
 from repro.wstrace.metrics import SchedulerMetrics
 
 
-def jit_decode_step_ws(cfg, *, schedule: str = "ws", bk: int = 64,
+def jit_decode_step_ws(cfg, *, schedule: str = "ws", bk: int | None = None,
                        n_programs: int = 8):
     """Compiled end-to-end WS decode step: ``jit(decode_step_ws)`` with the
     config closed over (it carries static shape info) and ``(params,
@@ -445,7 +445,8 @@ class ContinuousBatcher:
         ).astype(np.int64)
 
 
-def ragged_slot_attention(q, k_cache, v_cache, batcher_or_lengths, *, schedule=None, bk=64):
+def ragged_slot_attention(q, k_cache, v_cache, batcher_or_lengths, *, schedule=None,
+                          bk=None):
     """Decode attention over a continuous batcher's ragged slots.
 
     The engine's decode slots always hold wildly different sequence lengths

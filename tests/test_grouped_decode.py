@@ -3,10 +3,12 @@
 A decode tile's q block holds the G = H / Hkv query heads that share its KV
 head (zero-padded to whole float32 (8, 128) tiles), so each K/V block is
 copied and swept once for all G rows.  These tests hold the grouped launch
-to the dense oracle at G = 1, 3, 4 and 8, eagerly and under ``jit``; drill
-multiplicity on one grouped tile; and pin the launch's structure — tasks,
-rounds bound, q block — including that multi-head attention (G = 1) keeps
-the one-row tiles and records it always had.
+to the dense oracle at G = 1, 3, 4 and 8, eagerly and under ``jit``, at the
+fixed 64-position block and at the block :func:`decode_block` derives from
+the cache; drill multiplicity on one grouped tile; and pin the launch's
+structure — tasks, rounds bound, block length, q block — including that
+multi-head attention (G = 1) keeps the one-row tiles and records it always
+had.
 """
 
 import numpy as np
@@ -19,6 +21,7 @@ from conftest import apply_rewind, resume_state, RewindSpec  # noqa: E402
 from repro.pallas_ws import ragged  # noqa: E402
 from repro.pallas_ws.queues import make_queue_state  # noqa: E402
 from repro.pallas_ws.ragged import (  # noqa: E402
+    decode_block,
     decode_q_block,
     decode_q_rows,
     decode_q_unblock,
@@ -40,25 +43,62 @@ HKV, S, HD, BK = 8, 32, 8, 8
 LENGTHS = np.array([0, 1, BK, BK + 1, S])
 
 
-def _inputs(H, Hkv=HKV, B=len(LENGTHS), seed=11):
+# a float32 cache at hd 64 holds 512 positions in one derived block (128
+# KiB), as a bf16 one at hd 128 does; its capacity holds two such blocks
+EDGE_S, EDGE_HD = 1024, 64
+EDGE_BK = decode_block(EDGE_S, EDGE_HD, jnp.float32)
+# dead slot, one token, one short of the derived block, exactly one block,
+# one past it, full capacity
+EDGE_LENGTHS = np.array([0, 1, 511, 512, 513, EDGE_S])
+
+
+def _inputs(H, Hkv=HKV, B=len(LENGTHS), seed=11, S=S, hd=HD):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (B, H, HD))
-    k = jax.random.normal(ks[1], (B, Hkv, S, HD))
-    v = jax.random.normal(ks[2], (B, Hkv, S, HD))
+    q = jax.random.normal(ks[0], (B, H, hd))
+    k = jax.random.normal(ks[1], (B, Hkv, S, hd))
+    v = jax.random.normal(ks[2], (B, Hkv, S, hd))
     return q, k, v
 
 
+@pytest.mark.parametrize(
+    ("S", "hd", "dtype", "bk"),
+    [
+        (8192, 128, jnp.bfloat16, 512),   # both served configurations
+        (8192, 128, jnp.float32, 256),
+        (8192, 64, jnp.bfloat16, 1024),
+        (300, 128, jnp.bfloat16, 300),    # capacity under the target
+        (2112, 128, jnp.bfloat16, 64),    # 64 divides it, 512 does not
+        (2048 + 256, 128, jnp.bfloat16, 256),
+        (1000, 128, jnp.float32, 64),     # no power of two from 64 up divides it
+    ],
+    ids=["hd128-bf16", "hd128-f32", "hd64-bf16", "under-target", "S2112",
+         "S2304", "fallback"],
+)
+def test_decode_block_rule(S, hd, dtype, bk):
+    """About 128 KiB of K (or V) a block, capped at the capacity, and a
+    whole number of blocks in the cache wherever 64 gave one: the rule
+    adds no pad copy of the cache."""
+    got = decode_block(S, hd, dtype)
+    assert got == bk
+    assert got * hd * jnp.dtype(dtype).itemsize <= ragged.DECODE_BLOCK_BYTES
+    if S % 64 == 0 or S <= got:
+        assert S % got == 0
+
+
+@pytest.mark.parametrize("bk", [64, None], ids=["bk64", "derived"])
 @pytest.mark.parametrize("mode", ["eager", "jit"])
 @pytest.mark.parametrize("H", [8, 24, 32, 64], ids=lambda h: f"G{h // HKV}")
-def test_grouped_decode_matches_reference(H, mode):
-    q, k, v = _inputs(H)
+def test_grouped_decode_matches_reference(H, mode, bk):
+    """Lengths straddle the derived block's edge; ``bk=None`` is the rule."""
+    assert EDGE_BK == 512
+    q, k, v = _inputs(H, B=len(EDGE_LENGTHS), S=EDGE_S, hd=EDGE_HD)
     if mode == "eager":
-        out = ragged_decode_attention(q, k, v, LENGTHS, n_programs=4, bk=BK)
+        out = ragged_decode_attention(q, k, v, EDGE_LENGTHS, n_programs=4, bk=bk)
     else:
         out = jax.jit(
-            lambda ln: ragged_decode_attention(q, k, v, ln, n_programs=4, bk=BK)
-        )(jnp.asarray(LENGTHS))
-    ref = ragged_decode_ref(q, k, v, LENGTHS)
+            lambda ln: ragged_decode_attention(q, k, v, ln, n_programs=4, bk=bk)
+        )(jnp.asarray(EDGE_LENGTHS))
+    ref = ragged_decode_ref(q, k, v, EDGE_LENGTHS)
     assert out.shape == q.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -88,18 +128,19 @@ def test_grouped_tile_multiplicity_drill():
     """Rewind one queue's head by one slot (and wipe every program's local
     bound) after a finished launch, so exactly one (slot, KV head) tile runs
     a second time and adds a second copy of all its G rows.  Dividing by
-    mult[tid] gives back the single-run output exactly."""
+    mult[tid] gives back the single-run output exactly.  Tiles sweep the
+    derived block length."""
     H = 32
     G, G_pad = decode_q_rows(H, HKV)
-    lengths = np.array([S, BK + 1, 3])
-    q, k, v = _inputs(H, B=len(lengths))
-    tasks = emit_decode_tasks(lengths, HKV, BK, q_rows=G)
+    lengths = np.array([EDGE_S, EDGE_BK + 1, 3])
+    q, k, v = _inputs(H, B=len(lengths), S=EDGE_S, hd=EDGE_HD)
+    tasks = emit_decode_tasks(lengths, HKV, EDGE_BK, q_rows=G)
     state = make_queue_state(tasks, n_programs=4)
     qb = decode_q_block(q, HKV)
 
     def launch(**kw):
-        return run_ws_schedule(state, qb, k, v, causal=False, bq=G_pad, bk=BK,
-                               steal=True, **kw)
+        return run_ws_schedule(state, qb, k, v, causal=False, bq=G_pad,
+                               bk=EDGE_BK, steal=True, **kw)
 
     def normalized(res):
         div = multiplicity_divisor(tasks, res.mult, (len(lengths), HKV, G_pad))
@@ -136,7 +177,7 @@ def _spy_launch(monkeypatch):
     real = ragged.run_ws_schedule
 
     def spy(state, q, k, v, **kw):
-        seen.update(state=state, q_shape=q.shape, bq=kw["bq"],
+        seen.update(state=state, q_shape=q.shape, bq=kw["bq"], bk=kw["bk"],
                     rounds=kw["rounds"])
         return real(state, q, k, v, **kw)
 
@@ -145,9 +186,10 @@ def _spy_launch(monkeypatch):
 
 
 def test_traced_launch_builds_one_tile_per_kv_head(monkeypatch):
-    """The serving cell's widths (4 slots, 32/8 heads of 128, 2048 cache,
-    bk 64, 8 programs): B·Hkv = 32 tasks and 160 rounds, where one tile
-    per query head needed 128 tasks and 544 rounds."""
+    """The serving cell's widths (4 slots, 32/8 heads of 128, 2048 bf16
+    cache, 8 programs): B·Hkv = 32 tasks sweeping blocks of 512, so 20
+    rounds; at the fixed 64-position block the same tiles needed 160
+    rounds, and one tile per query head 128 tasks and 544 rounds."""
     B, H, Hkv, cap, hd = 4, 32, 8, 2048, 128
     assert decode_rounds_bound(B, Hkv, cap, 64, 8, 8, True) == 160
     assert decode_rounds_bound(B, H, cap, 64, 8, 8, True) == 544
@@ -158,7 +200,7 @@ def test_traced_launch_builds_one_tile_per_kv_head(monkeypatch):
     out = jax.eval_shape(ragged_decode_attention, q, kv, kv, ln)
     assert out.shape == (B, H, hd) and out.dtype == jnp.bfloat16
     assert seen["state"].n_tasks == B * Hkv
-    assert seen["rounds"] == 160
+    assert seen["bk"] == 512 and seen["rounds"] == 20
     assert seen["q_shape"] == (B, Hkv, 8, hd) and seen["bq"] == 8
 
 

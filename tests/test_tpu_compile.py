@@ -7,8 +7,9 @@ cannot catch (unaligned slices, vector access to SMEM, more fast memory
 than a kernel may use).  Shapes are the real widths: llama3.2-3b's heads
 and caches (3 query heads per KV head, so a decode tile's q block carries
 pad rows), the serving benchmark's Mistral-7B attention (4 per KV head),
-its Mistral-NeMo attention at 6 slots of an 8192-token cache (an 896-round
-grid a layer), and the expert tile at the width the chip check names.
+its Mistral-NeMo attention at 6 slots of an 8192-token cache (K/V blocks of
+512 positions, a 112-round grid a layer), and the expert tile at the width
+the chip check names.
 
 The code under test picks interpret mode from the backend, which is the
 CPU here, so each test switches the launch to the compiled kernel itself.
@@ -106,11 +107,23 @@ def test_decode_megakernel_compiles_at_mistral_width(one_chip,
 
 
 def test_decode_megakernel_compiles_at_nemo_long_context(one_chip,
-                                                         compiled_kernels):
-    from repro.pallas_ws.ragged import decode_rounds_bound
+                                                         compiled_kernels,
+                                                         monkeypatch):
+    """The launch sweeps 512-position blocks in 112 rounds; the fixed
+    64-position block would need 896."""
+    from repro.pallas_ws import ragged
 
-    assert decode_rounds_bound(NEMO_B, HKV, NEMO_S, 64, 8, 8, True) == 896
+    assert ragged.decode_rounds_bound(NEMO_B, HKV, NEMO_S, 64, 8, 8, True) == 896
+    seen = {}
+    real = ragged.run_ws_schedule
+
+    def spy(*args, **kw):
+        seen.update(bk=kw["bk"], rounds=kw["rounds"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(ragged, "run_ws_schedule", spy)
     compiled = _compile_decode_megakernel(one_chip, NEMO_B, NEMO_H, NEMO_S)
+    assert seen == {"bk": 512, "rounds": 112}
     assert _custom_calls(compiled) >= 1
 
 
