@@ -7,20 +7,16 @@ storms, or a whole seeded combination — through the segmented injector
 
     fault kind × steal policy {cost, scan} × queue layout {moe, attention}
 
-plus two serving cells on the real smoke engine:
-
-* ``replica_crash`` — a :class:`ReplicaCrashPlan` kills a replica
-  mid-run; the frontend re-admits its in-flight requests idempotently and
-  the greedy streams must be IDENTICAL to the fault-free run's streams;
-* ``watchdog`` — an :class:`EngineFaultPlan` poisons unified-step logits;
-  the batcher degrades to the split path and the streams must match the
-  clean unified run bitwise.
+plus a serving cell on the real smoke engine: ``replica_crash`` — a
+:class:`ReplicaCrashPlan` kills a replica mid-run; the frontend re-admits
+its in-flight requests idempotently and the greedy streams must be
+IDENTICAL to the fault-free run's streams.
 
 Reported per scheduler cell: checker verdict, max multiplicity, claim
 counts, ring drops, segment structure, and output parity ("bitwise" exact
 float replay for the single-source moe rows, "close"-or-better normalized
 parity for attention).  Per serving cell: completion/rejection sets,
-re-admission + degradation counts, stream parity.  The headline claims
+re-admission counts, stream parity.  The headline claims
 are absolute gates (exit 1):
 
 * every scheduler cell is checker-clean (no lost task, per-launch
@@ -279,47 +275,6 @@ def run_replica_crash_cell(*, crash_iter: int = 1, n_requests: int = 4,
     )
 
 
-def run_watchdog_cell(*, poison_steps=(0, 2), max_new: int = 3) -> dict:
-    import jax
-
-    from repro.chaos import EngineFaultPlan
-    from repro.configs import get_config
-    from repro.models import init_params
-    from repro.serving.engine import ContinuousBatcher, Request
-
-    cfg = get_config("llama3.2-3b", smoke=True)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    prompts = [np.array([5, 6, 7, 8], np.int32), np.array([9, 8, 7], np.int32)]
-
-    def one_run(fp):
-        b = ContinuousBatcher(params, cfg, slots=2, capacity=32,
-                              unified_step=True, fault_plan=fp)
-        for rid, p in enumerate(prompts):
-            assert b.admit(Request(rid, p, max_new=max_new))
-        done = []
-        for _ in range(24):
-            done += b.step()
-            if not b.n_live:
-                break
-        return b, {r.rid: list(map(int, r.out)) for r in done}
-
-    t0 = time.perf_counter()
-    b0, clean = one_run(None)
-    b1, faulted = one_run(EngineFaultPlan(poison_steps=tuple(poison_steps)))
-    degr = [d["kind"] for d in b1.degradations]
-    return dict(
-        section="serving", cell="watchdog",
-        poison_steps=list(poison_steps),
-        ok=bool(clean == faulted and degr
-                and all(k == "non-finite" for k in degr)
-                and not b0.degradations),
-        streams_match=bool(clean == faulted),
-        degradations=b1.degradations,
-        degradation_counts=b1.stats()["degradations"],
-        wall_s=round(time.perf_counter() - t0, 3),
-    )
-
-
 # ---------------------------------------------------------------------------
 # gates + entry point
 # ---------------------------------------------------------------------------
@@ -390,11 +345,6 @@ def main(argv=None):
     rows.append(run_replica_crash_cell())
     r = rows[-1]
     print(f"chaos,cell=replica_crash,ok={r['ok']},readmitted={r['readmitted']},"
-          f"streams_match={r['streams_match']}")
-    rows.append(run_watchdog_cell())
-    r = rows[-1]
-    print(f"chaos,cell=watchdog,ok={r['ok']},"
-          f"degradations={r['degradation_counts']},"
           f"streams_match={r['streams_match']}")
 
     status = check_claims(rows)

@@ -12,11 +12,11 @@ Sections:
                    shared-pool vs padded traced queue layouts (§3.6)
   [mesh]           cross-device mesh-ws vs per-device-static expert
                    sharding on 8 forced host devices (§7)
-  [serving]        replayed arrival traffic through the WS frontend —
-                   unified one-launch engine step vs split-launch (§5)
+  [serving]        replayed arrival traffic through the WS frontend over
+                   the served engine step (§5)
   [chaos]          seeded fault storms (stalls, advisory corruption,
                    kill+rewind) through the relaxed-semantics SafetyChecker,
-                   plus serving crash re-admission + watchdog parity
+                   plus serving crash re-admission parity
   [loader]         L2 host pipeline — work-stealing loader throughput
   [roofline]       dry-run roofline table (if results/dryrun.jsonl exists)
 
@@ -120,8 +120,8 @@ def summarize(quick: bool) -> dict:
     serving = _load("BENCH_serving", quick)
     if serving:
         # deterministic columns only: the trace replay is seeded and the
-        # engine single-threaded, so steps / utilization / counters / stream
-        # parity are exact; wall-clock latencies stay in BENCH_serving.json
+        # engine single-threaded, so steps / utilization / counters are
+        # exact; wall-clock latencies stay in BENCH_serving.json
         out["serving"] = [
             dict(
                 mode=r["mode"],
@@ -133,7 +133,6 @@ def summarize(quick: bool) -> dict:
                 rejected=len(r["rejected"]),
                 stolen=r["counters"]["stolen"],
                 dup_completed=r["counters"]["dup_completed"],
-                streams_match=serving["streams_match"][r["mode"]],
             )
             for r in serving["rows"]
         ]
@@ -155,11 +154,6 @@ def summarize(quick: bool) -> dict:
                 streams_match=cells["replica_crash"]["streams_match"],
                 readmitted=cells["replica_crash"]["readmitted"],
                 crashed=cells["replica_crash"]["crashed"],
-            ),
-            watchdog=dict(
-                ok=cells["watchdog"]["ok"],
-                streams_match=cells["watchdog"]["streams_match"],
-                degradations=cells["watchdog"]["degradation_counts"],
             ),
         )
     policy = _load("BENCH_policy", quick)
@@ -270,11 +264,10 @@ def main(argv=None):
         status |= mesh_dispatch.main(["--dry-run"] if args.quick else [])
 
     if "serving" in sections:
-        print("\n== [serving] replayed traffic: unified vs split engine step ==")
+        print("\n== [serving] replayed traffic through the served engine step ==")
         from . import serving_traffic
 
-        # nonzero when any rid is lost/duplicated or the unified one-launch
-        # step's token streams diverge from the split-launch oracle
+        # nonzero when any rid is lost or duplicated
         status |= serving_traffic.main(["--dry-run"] if args.quick else [])
 
     if "chaos" in sections:
@@ -283,7 +276,7 @@ def main(argv=None):
 
         # nonzero when any cell fails the checker (lost task, multiplicity
         # bound, double claim), output parity, the fault-off bitwise gate,
-        # or serving exactly-once / stream parity under crash + watchdog
+        # or serving exactly-once / stream parity under a replica crash
         status |= chaos_storm.main(["--dry-run"] if args.quick else [])
 
     if any(s in sections for s in ("ragged", "moe", "policy", "mesh", "serving", "chaos")):

@@ -1,5 +1,5 @@
 """Serving-under-load benchmark: replayed arrival traffic through the
-work-stealing frontend, unified megakernel step vs split-launch step.
+work-stealing frontend over the served engine step.
 
 Workload: a seeded arrival trace — ``poisson`` (geometric inter-arrival
 gaps, arrivals spread round-robin over the replicas) or ``bursty`` (whole
@@ -9,31 +9,24 @@ STEALING it) — replayed step-by-step through a
 first submits the arrivals whose timestamp has come due, then runs one
 round-robin admission+step pass over the replicas.
 
-Both decode paths run the SAME trace:
+The engine is the served construction (``bench/run.py`` builds the same):
+``ContinuousBatcher(jit_ws=True)`` — one compiled ``decode_step_ws`` per
+engine step plus a standalone jitted prefill per admission (path label
+``split``: the decode step and the prefill are separate launches).
 
-* ``split``    — the escape-hatch path: jitted ``decode_step_ws`` per step
-  plus a standalone jitted prefill per admission (2 launches per admitting
-  step, per replica);
-* ``unified``  — ``ContinuousBatcher(unified_step=True)``: ONE mixed-mode
-  ``launch_ws_grid`` launch per engine step carrying the decode tiles AND
-  the folded-in admission prefill (models.unified, DESIGN.md §5).
-
-Reported per path: p50/p99/mean per-step latency (ms), tokens/sec,
+Reported per trace mode: p50/p99/mean per-step latency (ms), tokens/sec,
 mean slot utilization, steps, and the frontend's scheduling counters
 (admitted / stolen / rejected / duplicates).  The correctness claims are
 absolute gates (exit 1):
 
 * every submitted rid completes exactly once (or is surfaced as rejected —
   over-capacity prompts are part of the trace on purpose);
-* the two paths produce **identical token streams** on the seeded trace —
-  the unified launch is bitwise vs the jitted split oracle, so greedy
-  streams may not diverge;
 * counter consistency: completed + duplicates == total admissions.
 
 Writes BENCH_serving.json next to this file (``--dry-run``:
 BENCH_serving.dryrun.json, tiny trace for CI; wall-clock numbers are
-recorded but only the deterministic columns — steps, utilization, counters,
-stream parity — are regression-gated by perf_smoke).
+recorded but only the deterministic columns — steps, utilization, counters
+— are regression-gated by perf_smoke).
 """
 
 from __future__ import annotations
@@ -148,7 +141,7 @@ def replay(fe, trace, max_iters: int = 10_000) -> dict:
 
 
 def run_one(slots: int, capacity: int, n_requests: int, max_new: int,
-            mode: str, unified: bool, *, arch: str = "llama3.2-3b",
+            mode: str, *, arch: str = "llama3.2-3b",
             n_replicas: int = 2, seed: int = 0) -> dict:
     import jax
 
@@ -160,50 +153,38 @@ def run_one(slots: int, capacity: int, n_requests: int, max_new: int,
     params = init_params(jax.random.PRNGKey(0), cfg)
 
     def make_batcher():
-        # split path jits the decode step so the two paths compare the
-        # compiled split-launch oracle against the (inherently compiled)
-        # unified megakernel, not eager-mode rounding noise
-        return ContinuousBatcher(
-            params, cfg, slots=slots, capacity=capacity,
-            unified_step=unified, jit_ws=not unified,
-        )
+        return ContinuousBatcher(params, cfg, slots=slots, capacity=capacity,
+                                 jit_ws=True)
 
     fe = WorkStealingFrontend(make_batcher, n_replicas=n_replicas)
     trace = make_trace(mode, n_requests, capacity, n_replicas,
                        seed=seed, max_new=max_new)
     row = replay(fe, trace)
-    row.update(mode=mode, path="unified" if unified else "split",
-               launches_per_step=1 if unified else "1 + prefill per admission")
+    row.update(mode=mode, path="split",
+               launches_per_step="1 + prefill per admission")
     return row
 
 
-def check_claims(rows_by_mode: dict) -> int:
-    """Absolute gates over a {mode: {'split': row, 'unified': row}} grid."""
+def check_claims(rows, n_requests: int) -> int:
+    """Absolute gates over the per-mode rows."""
     status = 0
-    for mode, pair in rows_by_mode.items():
-        for path, row in pair.items():
-            expect = row["_expect"]
-            got = set(row["completed"]) | set(row["rejected"])
-            dup = set(row["completed"]) & set(row["rejected"])
-            if got != expect or dup:
-                print(f"[serving] FAIL {mode}/{path}: completed+rejected "
-                      f"{sorted(got)} != submitted {sorted(expect)} "
-                      f"(overlap {sorted(dup)})")
-                status = 1
-            c = row["counters"]
-            admitted_net = c["admitted"] - c["dup_completed"]
-            if len(row["completed"]) != admitted_net:
-                print(f"[serving] FAIL {mode}/{path}: {len(row['completed'])} "
-                      f"completions vs admitted {c['admitted']} - dups "
-                      f"{c['dup_completed']}")
-                status = 1
-        if pair["split"]["streams"] != pair["unified"]["streams"]:
-            print(f"[serving] FAIL {mode}: unified token streams diverge "
-                  "from the split-launch oracle")
+    expect = set(range(n_requests))
+    for row in rows:
+        tag = f"{row['mode']}/{row['path']}"
+        got = set(row["completed"]) | set(row["rejected"])
+        dup = set(row["completed"]) & set(row["rejected"])
+        if got != expect or dup:
+            print(f"[serving] FAIL {tag}: completed+rejected "
+                  f"{sorted(got)} != submitted {sorted(expect)} "
+                  f"(overlap {sorted(dup)})")
             status = 1
-        else:
-            print(f"[serving] {mode}: unified == split on "
-                  f"{len(pair['split']['streams'])} request streams")
+        c = row["counters"]
+        admitted_net = c["admitted"] - c["dup_completed"]
+        if len(row["completed"]) != admitted_net:
+            print(f"[serving] FAIL {tag}: {len(row['completed'])} "
+                  f"completions vs admitted {c['admitted']} - dups "
+                  f"{c['dup_completed']}")
+            status = 1
     return status
 
 
@@ -230,35 +211,25 @@ def main(argv=None):
         slots, capacity, n_requests, max_new = 2, 48, 10, 4
         modes = ("poisson", "bursty")
 
-    rows_by_mode = {}
     rows = []
     for mode in modes:
-        pair = {}
-        for unified in (False, True):
-            row = run_one(slots, capacity, n_requests, max_new, mode, unified)
-            row["_expect"] = set(range(n_requests))
-            pair["unified" if unified else "split"] = row
-            print(
-                f"serving,mode={mode},path={row['path']},steps={row['steps']},"
-                f"tokens_per_sec={row['tokens_per_sec']},"
-                f"util={row['slot_utilization']},"
-                f"p50_ms={row['latency_ms']['p50'] if row['latency_ms'] else None},"
-                f"p99_ms={row['latency_ms']['p99'] if row['latency_ms'] else None},"
-                f"rejected={len(row['rejected'])},stolen={row['counters']['stolen']}"
-            )
-        rows_by_mode[mode] = pair
-        rows.extend(pair.values())
+        row = run_one(slots, capacity, n_requests, max_new, mode)
+        rows.append(row)
+        print(
+            f"serving,mode={mode},path={row['path']},steps={row['steps']},"
+            f"tokens_per_sec={row['tokens_per_sec']},"
+            f"util={row['slot_utilization']},"
+            f"p50_ms={row['latency_ms']['p50'] if row['latency_ms'] else None},"
+            f"p99_ms={row['latency_ms']['p99'] if row['latency_ms'] else None},"
+            f"rejected={len(row['rejected'])},stolen={row['counters']['stolen']}"
+        )
 
-    status = check_claims(rows_by_mode)
-    for row in rows:
-        row.pop("_expect", None)
+    status = check_claims(rows, n_requests)
     payload = dict(
         config=dict(slots=slots, capacity=capacity, n_requests=n_requests,
                     max_new=max_new, n_replicas=2, seed=0,
                     dry_run=args.dry_run),
         rows=rows,
-        streams_match={m: p["split"]["streams"] == p["unified"]["streams"]
-                       for m, p in rows_by_mode.items()},
     )
     pathlib.Path(args.out).write_text(json.dumps(payload, indent=2))
     print(f"[serving] wrote {args.out}")

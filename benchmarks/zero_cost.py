@@ -438,69 +438,53 @@ def audit_batched_put_host(n: int = 4096, segment: int = 64) -> List[Dict]:
     return rows
 
 
-def audit_unified_step() -> List[Dict]:
-    """The unified-engine-step analogue of :func:`audit_traced_put`
-    (DESIGN.md §5): lower the whole ``decode_step_unified`` pipeline — the
-    stage-gated mixed-mode queue build (decode tiles + prefill flash tiles
-    + expert tiles + step glue in ONE ``launch_ws_grid`` grid) and its
-    family-dispatching megakernel drain — and assert the StableHLO carries
-    **zero** RMW / atomic / lock / fence operations.
+# (experiment, config, overrides) of the served-step audit: a dense GQA
+# step, and a MoE step whose expert FFN runs as WS expert tiles in the step
+SERVED_STEP_CASES = (
+    ("served-step-dense", "llama3.2-3b", {}),
+    ("served-step-moe-ws", "kimi-k2-1t-a32b", {"moe_dispatch": "ws"}),
+)
 
-    Two cells: the dense decode-only step (llama smoke config) and the full
-    mixed-mode step (MoE config with ``moe_dispatch="ws"`` AND a folded-in
-    prefill chunk — all four task families in the one lowering).  ``pos``
-    is static per (slots, capacity) shape — the engine re-lowers per length
-    vector in interpret mode — so it is closed over concretely; params,
-    caches and tokens are traced.
+
+def audit_served_step(cases=SERVED_STEP_CASES) -> List[Dict]:
+    """The whole-step analogue of :func:`audit_traced_put`: lower the
+    compiled decode step the serving engine runs
+    (``repro.serving.engine.jit_decode_step_ws`` — traced Put of every
+    layer's decode tiles, and of the expert tiles under
+    ``moe_dispatch="ws"``, drained by the WS megakernel) and assert the
+    StableHLO carries **zero** RMW / atomic / lock / fence operations.
+
+    Smoke configs at 2 slots × 32 positions; params, caches, tokens and
+    positions are all traced, as in the engine.
     """
-    import dataclasses as dc
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.configs import get_config
-    from repro.models import decode_step_unified, init_params, prefill
+    from repro.models import init_params, prefill
+    from repro.serving.engine import jit_decode_step_ws
 
     cap = 32
-    pos = np.array([4, 2], np.int32)
-    cases = (
-        ("put-take-unified", "llama3.2-3b", {}, False),
-        ("put-steal-unified-mixed", "kimi-k2-1t-a32b",
-         {"moe_dispatch": "ws"}, True),
-    )
     rows = []
-    for exp, arch, overrides, with_prefill in cases:
+    for exp, arch, overrides in cases:
         cfg = get_config(arch, smoke=True)
         if overrides:
-            cfg = dc.replace(cfg, **overrides)
+            cfg = cfg.replace(**overrides)
         params = init_params(jax.random.PRNGKey(0), cfg)
         batch = {"tokens": jnp.asarray(
             np.array([[5, 6, 7, 8], [9, 8, 7, 6]], np.int32))}
         _, caches = prefill(params, cfg, batch, capacity=cap)
         tok = jnp.asarray(np.array([[3], [4]], np.int32))
-        ptok = (jnp.asarray(np.arange(11, 18, dtype=np.int32)[None, :])
-                if with_prefill else None)
-
-        def pipeline(params, caches, tok, cfg=cfg, ptok=ptok):
-            logits, c1, rep = decode_step_unified(
-                params, cfg, caches, tok, pos, prefill_tokens=ptok,
-            )
-            outs = (logits, c1.kv.k, c1.kv.v, rep.res.mult)
-            if ptok is not None:
-                outs += (rep.prefill_logits, rep.prefill_kv.k)
-            return outs
-
-        text = jax.jit(pipeline).lower(params, caches, tok).as_text()
-        tag = "mixed(decode+prefill+expert+glue)" if with_prefill else "decode"
+        pos = jnp.asarray(np.array([4, 2], np.int32))
+        text = jit_decode_step_ws(cfg).lower(params, caches, tok, pos).as_text()
         rows.append(_fence_free_lowering_row(
-            text, f"unified step lowering [{tag}]", exp,
-            f"unified-step[{tag}]", int(pos.size),
+            text, f"served step lowering [{arch}]", exp,
+            f"served-step[{arch}]", int(pos.size),
         ))
     print(
-        "[zero-cost] unified-step audit OK: the one-launch mixed-mode "
-        "engine step (decode + folded prefill + expert + glue families in "
-        "a single launch_ws_grid lowering) has 0 RMW / 0 locks / 0 fences"
+        "[zero-cost] served-step audit OK: the compiled WS decode step "
+        f"({', '.join(c[1] for c in cases)}) has 0 RMW / 0 locks / 0 fences"
     )
     return rows
 
@@ -523,7 +507,7 @@ def main(n_ops: int = 100_000):
         import jax  # noqa: F401
 
         rows.extend(audit_traced_put())
-        rows.extend(audit_unified_step())
+        rows.extend(audit_served_step())
     except ImportError:
         print("[zero-cost] jax unavailable — traced-put audit skipped")
     return rows

@@ -5,8 +5,8 @@ Scheduler layer: :class:`FaultPlan` (seeded stalls / advisory corruption /
 head-rewind storms / kill-and-relaunch) driven through launch segments by
 :func:`run_with_faults`, with :class:`SafetyChecker` verifying the paper's
 §7 contract (no lost task, bounded multiplicity, normalized bit-parity)
-over the trace rings.  Serving layer: :class:`ReplicaCrashPlan` and
-:class:`EngineFaultPlan` for replica crashes and watchdog drills.
+over the trace rings.  Serving layer: :class:`ReplicaCrashPlan` for
+replica crashes.
 """
 
 from repro.chaos.checker import ChaosReport, SafetyChecker, Violation
@@ -20,13 +20,12 @@ from repro.chaos.plan import (
     resume_state,
     seed_advisory,
 )
-from repro.chaos.serving import EngineFaultPlan, ReplicaCrashPlan
+from repro.chaos.serving import ReplicaCrashPlan
 
 __all__ = [
     "ADVISORY_MODES",
     "ChaosReport",
     "ChaosRunResult",
-    "EngineFaultPlan",
     "FaultPlan",
     "ReplicaCrashPlan",
     "RewindSpec",

@@ -13,16 +13,13 @@ from .model import (
 )
 from .sharding import param_shardings, shard, use_mesh
 from .transformer import init_params
-from .unified import UnifiedStepReport, decode_step_unified, unified_step_supported
 
 __all__ = [
     "Caches",
     "ModelConfig",
-    "UnifiedStepReport",
     "SHAPES",
     "ShapeConfig",
     "decode_step",
-    "decode_step_unified",
     "decode_step_ws",
     "init_caches",
     "init_params",
@@ -31,7 +28,6 @@ __all__ = [
     "prefill",
     "shard",
     "shard_caches",
-    "unified_step_supported",
     "use_mesh",
     "ws_decode_supported",
 ]

@@ -62,9 +62,9 @@ in :mod:`repro.moe_ws.expert_kernel`.
 
 Memory spaces (DESIGN.md §3.7).  Every array enters the launch in HBM
 (``memory_space=pltpu.HBM``).  The scheduler state — task records, tails, pool
-offsets, stage-open rounds and the nine mutable queue/telemetry arrays — is
-copied once into SMEM scratch at the launch's first grid cell and back at
-its last, so the protocol above is scalar SMEM loads and stores.  Family
+offsets and the nine mutable queue/telemetry arrays — is copied once into
+SMEM scratch at the launch's first grid cell and back at its last, so the
+protocol above is scalar SMEM loads and stores.  Family
 operands and outputs stay in HBM: each tile copies the blocks it needs into
 VMEM scratch and writes its accumulated output block back, so fast-memory
 use does not grow with slots × capacity.  The DMA semaphores those copies
@@ -137,14 +137,13 @@ VMEM_LIMIT_BYTES = 100 * 2**20
 
 # Order of the mutable (input-output aliased) queue/telemetry arrays every
 # family launch carries: head, local_head, taken, remaining, clock, work,
-# steals, scanned, mult, then the family outputs.  ``launch_ws_grid`` owns
-# this layout.  A multi-output launch (``out`` given as a tuple — the
-# unified engine step) carries one slot per output, and a traced launch
-# (``trace=True``) appends two more — the event rings and their per-program
-# cursors (``repro.wstrace.ring``) — after the outputs.  The scheduler
-# arrays are passed flat (``local_head`` as ``[P·n_queues]``, ``taken`` and
-# ``tasks`` row-major) so their SMEM mirrors are 1-D word arrays.
-N_SCHED_MUTABLE = 9   # head..mult, before the family outputs
+# steals, scanned, mult, then the family output.  ``launch_ws_grid`` owns
+# this layout.  A traced launch (``trace=True``) appends two more — the
+# event rings and their per-program cursors (``repro.wstrace.ring``) —
+# after the output.  The scheduler arrays are passed flat (``local_head``
+# as ``[P·n_queues]``, ``taken`` and ``tasks`` row-major) so their SMEM
+# mirrors are 1-D word arrays.
+N_SCHED_MUTABLE = 9   # head..mult, before the family output
 
 
 def _slot_row(pool_off_ref, v, s, *, pool: bool, capacity: int):
@@ -194,7 +193,7 @@ def _probe_slot(
 
 def ws_try_extract(
     r, p, head_ref, local_head_ref, tail_ref, remaining_ref, tasks_ref,
-    clock_ref, pool_off_ref=None, stage_ref=None,
+    clock_ref, pool_off_ref=None,
     *, n_queues: int, capacity: int, steal: bool,
     steal_policy: str = "cost", pool: bool = False, steal_run_cap: int = 1,
 ):
@@ -204,13 +203,6 @@ def ws_try_extract(
     configured policy and claims the first live slot with plain writes only.
     Returns ``(found, queue, slot, run, slots_read)``; no-op (found=False)
     while the program's clock says it is still busy with its previous tile.
-
-    ``stage_ref`` (optional, [n_queues] int32): per-queue open rounds for
-    stage-gated launches (the unified engine step) — a queue is invisible to
-    probes and to the victim mask until ``stage_ref[q] <= r``.  Gating is a
-    pure *input* (no cross-program signalling): the stage windows are sized
-    on the host by the Graham bound so every task of stage ``s`` has
-    finished before ``stage_ref`` opens stage ``s+1`` (DESIGN.md §5).
 
     ``steal_run_cap > 1`` (cost policy only) amortizes Steal probes: one
     successful victim probe claims ``min(ceil(rem/2), cap)`` *contiguous*
@@ -236,9 +228,6 @@ def ws_try_extract(
         pool=pool, capacity=capacity,
     )
 
-    def stage_open(v):
-        return jnp.bool_(True) if stage_ref is None else stage_ref[v] <= r
-
     def lh(v):
         # flat [P·n_queues] local bounds: program p's row starts at p·n_queues
         return p * n_queues + v
@@ -254,7 +243,7 @@ def ws_try_extract(
             found, fq, fs, nread = carry
             v = jax.lax.rem(p + j, n_queues)
             h = jnp.maximum(local_head_ref[lh(v)], head_ref[v])  # RMaxRead
-            op, issued = probe(v, h, (~found) & stage_open(v))
+            op, issued = probe(v, h, ~found)
             live = op != BOTTOM
             claim = (~found) & live
 
@@ -278,7 +267,7 @@ def ws_try_extract(
         """O(1) policy: own-queue probe, then cost-aware victim argmax."""
         own = jax.lax.rem(p, n_queues)
         h0 = jnp.maximum(local_head_ref[lh(own)], head_ref[own])  # RMaxRead
-        op0, issued0 = probe(own, h0, stage_open(own))
+        op0, issued0 = probe(own, h0, jnp.bool_(True))
         own_live = op0 != BOTTOM
 
         @pl.when(own_live)
@@ -299,7 +288,7 @@ def ws_try_extract(
         def rank(j, carry):
             best, bv, bh = carry
             hj = jnp.maximum(local_head_ref[lh(j)], head_ref[j])
-            ok = (hj < tail_ref[j]) & stage_open(j)
+            ok = hj < tail_ref[j]
             score = jnp.where(ok, jnp.maximum(remaining_ref[j], 1), 0)
             better = score > best
             return (jnp.where(better, score, best), jnp.where(better, j, bv),
@@ -374,9 +363,7 @@ def _generic_ws_kernel(
     *refs,
     n_pure: int,
     n_smem_pure: int,
-    n_outs: int,
     pool: bool,
-    staged: bool,
     trace: bool,
     **cell_kw,
 ):
@@ -384,23 +371,23 @@ def _generic_ws_kernel(
     per-cell protocol (:func:`_ws_cell`).
 
     Ref layout (positional, fixed by :func:`launch_ws_grid`), every array in
-    HBM: the mutable inputs (9 flat scheduler arrays, ``n_outs`` family
-    outputs, + event rings and cursors when ``trace``), the scheduler's
-    read-only inputs (flat task records, tails, the pool segment offsets
-    when ``pool``, the stage-open rounds when ``staged``), the
-    ``n_smem_pure`` family inputs the body reads as scalars, the ``n_pure``
-    family inputs it copies blocks of; then the aliased outputs in the order
-    of the mutable inputs; then the SMEM scratch — one mirror per scheduler
-    array (mutable, then read-only) and per scalar family input, plus the
-    cursor mirror and one event-record row when ``trace``.
+    HBM: the mutable inputs (9 flat scheduler arrays, the family output,
+    + event rings and cursors when ``trace``), the scheduler's read-only
+    inputs (flat task records, tails, the pool segment offsets when
+    ``pool``), the ``n_smem_pure`` family inputs the body reads as scalars,
+    the ``n_pure`` family inputs it copies blocks of; then the aliased
+    outputs in the order of the mutable inputs; then the SMEM scratch — one
+    mirror per scheduler array (mutable, then read-only) and per scalar
+    family input, plus the cursor mirror and one event-record row when
+    ``trace``.
 
     The first grid cell copies the scheduler state into its SMEM mirrors
     (reading the mutable arrays through their aliased output refs, which
     hold the input values); the last cell copies the mutable mirrors back.
     In between, every cell is plain scalar SMEM loads and stores.
     """
-    n_mut = N_SCHED_MUTABLE + n_outs + (2 if trace else 0)
-    n_ro = 2 + int(pool) + int(staged) + n_smem_pure
+    n_mut = N_SCHED_MUTABLE + 1 + (2 if trace else 0)
+    n_ro = 2 + int(pool) + n_smem_pure
     n_in = n_mut + n_ro + n_pure
     ro_in = refs[n_mut: n_mut + n_ro]
     hbm_pure = refs[n_mut + n_ro: n_in]
@@ -410,7 +397,7 @@ def _generic_ws_kernel(
     ro = smem[N_SCHED_MUTABLE: N_SCHED_MUTABLE + n_ro]
     hbm_mut = outs[:N_SCHED_MUTABLE]
     if trace:
-        ev_ref, cursor_hbm = outs[N_SCHED_MUTABLE + n_outs:]
+        ev_ref, cursor_hbm = outs[N_SCHED_MUTABLE + 1:]
         cursor_ref, ev_row_ref = smem[N_SCHED_MUTABLE + n_ro:]
         mirrored = tuple(sched_mut) + (cursor_ref,)
         hbm_mut = tuple(hbm_mut) + (cursor_hbm,)
@@ -429,13 +416,12 @@ def _generic_ws_kernel(
 
     tasks_ref, tail_ref = ro[:2]
     pool_off_ref = ro[2] if pool else None
-    stage_ref = ro[2 + int(pool)] if staged else None
     pure = tuple(ro[n_ro - n_smem_pure:]) + tuple(hbm_pure)
-    out_refs = outs[N_SCHED_MUTABLE: N_SCHED_MUTABLE + n_outs]
+    out_ref = outs[N_SCHED_MUTABLE]
     _ws_cell(
-        r, p, *sched_mut, tasks_ref, tail_ref, pool_off_ref, stage_ref,
-        pure, out_refs, ev_ref, cursor_ref, ev_row_ref,
-        pool=pool, staged=staged, trace=trace, **cell_kw,
+        r, p, *sched_mut, tasks_ref, tail_ref, pool_off_ref,
+        pure, out_ref, ev_ref, cursor_ref, ev_row_ref,
+        pool=pool, trace=trace, **cell_kw,
     )
 
     @pl.when(last)
@@ -447,8 +433,8 @@ def _ws_cell(
     r, p,
     head_ref, local_head_ref, taken_ref, remaining_ref, clock_ref, work_ref,
     steals_ref, scanned_ref, mult_ref,
-    tasks_ref, tail_ref, pool_off_ref, stage_ref,
-    pure, out_refs, ev_ref, ev_cursor_ref, ev_row_ref,
+    tasks_ref, tail_ref, pool_off_ref,
+    pure, out_ref, ev_ref, ev_cursor_ref, ev_row_ref,
     *,
     execute: Callable,
     n_queues: int,
@@ -458,22 +444,15 @@ def _ws_cell(
     pool: bool,
     compress: bool,
     steal_run_cap: int,
-    multi_out: bool,
-    staged: bool,
     trace: bool,
     trace_capacity: int,
     steal_kind: int,
 ):
     """One grid cell of the persistent WS grid: program ``p`` at round
     ``r`` tries one Take/Steal (or, compressed, drains its own queue) and
-    runs the family ``execute`` body on each claimed record.
-
-    ``multi_out`` launches call ``execute(rec, pure, outs, mult_ref)`` with
-    the tuple of output refs plus the live multiplicity counters (the
-    unified step's glue phases normalize accumulators in-kernel); the
-    single-output convention stays ``execute(rec, pure, out_ref)``.
+    runs the family ``execute(rec, pure, out_ref)`` body on each claimed
+    record.
     """
-    out_ref = out_refs if multi_out else out_refs[0]
 
     def trace_append(fq, fs, tid, cost, t0, op, run):
         """Append one extraction record to program ``p``'s event ring —
@@ -519,10 +498,7 @@ def _ws_cell(
             # the tile-slots the program is busy (also correct inside a
             # compressed drain run, where the clock advances per extraction)
             t0 = jnp.maximum(clock_ref[p], r)
-        if multi_out:
-            execute(rec, pure, out_ref, mult_ref)
-        else:
-            execute(rec, pure, out_ref)
+        execute(rec, pure, out_ref)
         ws_account(
             r, p, fq, fs, rec(F_TID), rec(F_COST),
             taken_ref, remaining_ref, clock_ref, work_ref, steals_ref,
@@ -541,7 +517,6 @@ def _ws_cell(
         # makespan/work telemetry to the per-round drain), but the grid
         # needs O(1) rounds instead of max-queue-cost rounds.
         assert not steal, "run compression models the no-steal schedule only"
-        assert not staged, "stage gating needs the per-round lockstep"
         own = jax.lax.rem(p, n_queues)
 
         def probe_own():
@@ -583,7 +558,7 @@ def _ws_cell(
 
     found, fq, fs, run, nread = ws_try_extract(
         r, p, head_ref, local_head_ref, tail_ref, remaining_ref, tasks_ref,
-        clock_ref, pool_off_ref, stage_ref,
+        clock_ref, pool_off_ref,
         n_queues=n_queues, capacity=capacity, steal=steal,
         steal_policy=steal_policy, pool=pool, steal_run_cap=steal_run_cap,
     )
@@ -737,7 +712,6 @@ def launch_ws_grid(
     rounds: Optional[int] = None,
     mult: Optional[jax.Array] = None,
     compress_runs: Optional[bool] = None,
-    stage_open: Optional[jax.Array] = None,
     trace: bool = False,
     trace_capacity: Optional[int] = None,
     trace_remote: bool = False,
@@ -762,17 +736,6 @@ def launch_ws_grid(
     launches keep the one-extraction-per-round lockstep so thief
     concurrency stays faithfully modeled.
 
-    ``out`` may be a *tuple* of arrays (the unified engine step's caches,
-    activation buffers, routing scratch, logits).  The shell then calls
-    ``execute(rec, pure_refs, out_refs, mult_ref)`` — the tuple of live
-    output refs plus the multiplicity counters, so mixed-family bodies can
-    normalize accumulators in-kernel — and ``WSRunResult.out`` is the tuple
-    in the same order.  ``stage_open`` ([n_queues] int32, optional) gates
-    extraction per queue by round (see :func:`ws_try_extract`): the
-    mixed-mode launch encodes its inter-stage dependencies as host-computed
-    open rounds instead of device-side waiting, keeping the lowering free
-    of fences.
-
     ``trace=True`` additionally records every extraction into per-program
     event rings (``WSRunResult.events``/``ev_cursor``; schema in
     :mod:`repro.wstrace.ring`) with plain stores only.  The default ring
@@ -789,8 +752,7 @@ def launch_ws_grid(
     slots and executes them back-to-back in the claiming grid cell, with ONE
     coalesced advisory write per run (see :func:`ws_try_extract`).  The
     default ``1`` lowers bit-identically to the per-slot claim; ``> 1`` is
-    incompatible with ``stage_open`` (the Graham stage windows assume
-    per-slot claims) and with ``compress_runs``.  The Graham rounds bound
+    incompatible with ``compress_runs``.  The Graham rounds bound
     and the default trace-ring capacity gain a ``cap`` slack term.
 
     ``fault_plan`` (a :class:`repro.chaos.FaultPlan`, optional) injects
@@ -805,28 +767,20 @@ def launch_ws_grid(
     :func:`repro.chaos.inject.run_with_faults`.
 
     ``name`` names the ``pallas_call``, one per task family (``ws_decode``,
-    ``ws_flash``, ``ws_attention``, ``ws_expert``, ``ws_expert_grad``,
-    ``ws_unified``), so that a profile says which family ran.
+    ``ws_flash``, ``ws_attention``, ``ws_expert``, ``ws_expert_grad``), so
+    that a profile says which family ran.
     """
     assert steal_policy in STEAL_POLICIES, steal_policy
     P = state.n_programs
     compress = (not steal) if compress_runs is None else compress_runs
     if compress and steal:
         raise ValueError("compress_runs models the no-steal schedule only")
-    if stage_open is not None and compress:
-        raise ValueError("stage_open needs the per-round lockstep "
-                         "(compress_runs=False)")
     if steal_run_cap < 1:
         raise ValueError(f"steal_run_cap must be >= 1, got {steal_run_cap}")
     if steal_run_cap > 1:
         if not steal or steal_policy != "cost":
             raise ValueError("steal_run_cap > 1 amortizes cost-policy "
                              "steals — needs steal=True, steal_policy='cost'")
-        if stage_open is not None:
-            raise ValueError("steal_run_cap > 1 breaks the per-slot-claim "
-                             "assumption of stage_open's Graham windows")
-    multi_out = isinstance(out, (tuple, list))
-    outs_in = tuple(out) if multi_out else (out,)
     rounds_given = rounds is not None
     rounds = (
         default_rounds(state, steal, compress_runs=compress,
@@ -873,9 +827,6 @@ def launch_ws_grid(
         pool=pool,
         compress=compress,
         steal_run_cap=steal_run_cap,
-        n_outs=len(outs_in),
-        multi_out=multi_out,
-        staged=stage_open is not None,
         trace=trace,
         trace_capacity=trace_capacity,
         steal_kind=steal_kind,
@@ -893,7 +844,7 @@ def launch_ws_grid(
         jnp.zeros((P,), i32),         # scanned
         jnp.asarray(mult, i32),
     ]
-    mutable = sched + [jnp.asarray(o) for o in outs_in]
+    mutable = sched + [jnp.asarray(out)]
     if trace:
         mutable += [
             jnp.full((P, trace_capacity, EVENT_WIDTH), -1, i32),
@@ -903,8 +854,6 @@ def launch_ws_grid(
                 jnp.asarray(state.tail, i32)]
     if pool:
         sched_in.append(jnp.asarray(state.pool_off, i32))
-    if stage_open is not None:
-        sched_in.append(jnp.asarray(stage_open, i32))
     sched_in += [jnp.asarray(a) for a in smem_pure]
     pure_arrays = [jnp.asarray(a) for a in pure]
     smem = [pltpu.SMEM(a.shape, a.dtype) for a in sched + sched_in]
@@ -927,15 +876,12 @@ def launch_ws_grid(
         interpret=interpret_mode(),
         name=name,
     )(*mutable, *sched_in, *pure_arrays)
-    n_live = N_SCHED_MUTABLE + len(outs_in)
+    n_live = N_SCHED_MUTABLE + 1
     (head, local_head, taken, remaining, clock, work, steals, scanned,
      mult) = outs[:N_SCHED_MUTABLE]
     local_head = local_head.reshape(np.shape(state.local_head))
     taken = taken.reshape(np.shape(state.taken))
-    out = (
-        tuple(outs[N_SCHED_MUTABLE:n_live]) if multi_out
-        else outs[N_SCHED_MUTABLE]
-    )
+    out = outs[N_SCHED_MUTABLE]
     events, ev_cursor = outs[n_live:] if trace else (None, None)
 
     def host(a):

@@ -179,73 +179,6 @@ def make_queue_state(
     )
 
 
-def make_staged_queue_state(
-    stages: Sequence[Sequence[TileTask]],
-    n_programs: int,
-    *,
-    n_queues_per_stage: Optional[int] = None,
-    partition: str = "owner",
-) -> Tuple[QueueState, np.ndarray, int]:
-    """Host-side Put for a *stage-gated* mixed-mode launch (DESIGN.md §5).
-
-    ``stages[s]`` is the task list of stage ``s`` (any registered family —
-    the unified engine step mixes glue, attention, and expert records in
-    one launch).  Each stage gets its own block of queues, laid out
-    stage-major, and the whole sequence runs as ONE ``launch_ws_grid``
-    call: inter-stage dependencies are enforced purely by the returned
-    ``stage_open`` vector — queue ``q`` of stage ``s`` becomes visible to
-    Take/Steal only at round ``open[s]``, where the open rounds are the
-    prefix sums of each stage's Graham bound
-
-        open[0] = 0;  open[s+1] = open[s] + ceil(W_s / P) + max_cost_s
-
-    (``W_s`` total stage cost).  Because an idle program always claims a
-    task whenever any open queue is non-empty (the cost policy's
-    ``head < tail`` victim mask is exact), every stage-``s`` task has
-    *finished* — clock-wise and write-wise — by ``open[s+1]``, so stage
-    ``s+1`` bodies read completed stage-``s`` output.  No device-side
-    waiting, no fence: the dependency structure is a pure input.
-
-    Returns ``(state, stage_open, rounds)`` — ``stage_open`` is per-queue
-    ([n_queues] int32) and ``rounds = open[n_stages]`` is the static grid
-    bound covering the final stage's window.
-    """
-    q_s = n_programs if n_queues_per_stage is None else n_queues_per_stage
-    buckets: List[List[TileTask]] = []
-    opens = [0]
-    task_list: List[TileTask] = []
-    for tasks in stages:
-        buckets += partition_tasks(tasks, q_s, partition)
-        task_list += list(tasks)
-        total = sum(t.cost for t in tasks)
-        mc = max((t.cost for t in tasks), default=0)
-        window = (-(-total // n_programs) + mc) if tasks else 0
-        opens.append(opens[-1] + window)
-    n_queues = len(buckets)
-    cap = max(4, max((len(b) for b in buckets), default=0) + 2)
-    arr = np.full((n_queues, cap, TASK_WIDTH), BOTTOM, dtype=np.int32)
-    tail = np.zeros((n_queues,), dtype=np.int32)
-    remaining = np.zeros((n_queues,), dtype=np.int32)
-    for q, bucket in enumerate(buckets):
-        for s, t in enumerate(bucket):
-            arr[q, s] = t.encode()
-        tail[q] = len(bucket)
-        remaining[q] = sum(t.cost for t in bucket)
-    state = QueueState(
-        tasks=arr,
-        head=np.zeros((n_queues,), dtype=np.int32),
-        tail=tail,
-        local_head=np.zeros((n_programs, n_queues), dtype=np.int32),
-        taken=np.full((n_queues, cap), -1, dtype=np.int32),
-        task_list=task_list,
-        remaining=remaining,
-    )
-    stage_open = np.repeat(
-        np.asarray(opens[:-1], dtype=np.int32), q_s
-    )
-    return state, stage_open, max(1, opens[-1])
-
-
 def copy_state(state: QueueState) -> QueueState:
     """Independent copy of a host-built queue state (numpy arrays copied,
     task_list shared — tasks are immutable records).  Fault-injection

@@ -56,15 +56,9 @@ F_E = 1       # expert id (indexes the stacked expert weight arrays)
 F_RS = 2      # first routed row of the tile (into the grouped routed arrays)
 F_RL = 3      # number of live routed rows (< bt on a ragged tail tile)
 
-# -- step-glue family operands (fields 1-3; 4-5 unused) ----------------------
-F_PHASE = 1   # glue phase kind (models.unified.GLUE_* codes)
-F_LAYER = 2   # transformer layer the glue belongs to
-F_AUX = 3     # phase-specific operand (e.g. prefill slot; BOTTOM if unused)
-
 OP_FLASH_TILE = 0
 OP_DECODE_TILE = 1
 OP_EXPERT_TILE = 2
-OP_STEP_GLUE = 3
 
 
 @dataclass(frozen=True)
@@ -116,20 +110,6 @@ EXPERT_FAMILY = register_family(
         ops=(OP_EXPERT_TILE,),
         operands=("expert", "row_start", "row_len"),
         cost_unit="routed token rows",
-    )
-)
-
-# Inter-stage glue of the unified engine step (models.unified): norms, qkv
-# projections + cache writes, routing, combines, logits.  Exactly one task
-# per (phase, layer), so a glue task's cost is the whole phase's work — the
-# unified launch charges it as the stage's max_cost term in the Graham
-# window bound (DESIGN.md §5).
-STEP_FAMILY = register_family(
-    TaskFamily(
-        name="step-glue",
-        ops=(OP_STEP_GLUE,),
-        operands=("phase", "layer", "aux"),
-        cost_unit="glue phases",
     )
 )
 
@@ -186,35 +166,6 @@ class ExpertTask:
     def encode(self) -> np.ndarray:
         return np.array(
             [self.op, self.expert, self.row_start, self.row_len,
-             BOTTOM, BOTTOM, self.tid, self.cost],
-            dtype=np.int32,
-        )
-
-
-@dataclass(frozen=True)
-class StepGlueTask:
-    """Step-glue task: one inter-stage phase of the unified engine step.
-
-    Glue phases are serial by construction (one task per phase, gated by the
-    stage windows), so duplication is impossible on a correct schedule — but
-    the body still accumulates idempotently and ``mult[tid]`` still counts,
-    keeping the family honest under the relaxed scheduler's contract.
-    """
-
-    phase: int
-    layer: int
-    aux: int
-    tid: int
-    cost: int
-    op: int = OP_STEP_GLUE
-
-    @property
-    def owner(self) -> int:
-        return self.layer
-
-    def encode(self) -> np.ndarray:
-        return np.array(
-            [self.op, self.phase, self.layer, self.aux,
              BOTTOM, BOTTOM, self.tid, self.cost],
             dtype=np.int32,
         )

@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -40,16 +39,12 @@ import numpy as np
 
 from repro.core import EMPTY, WSWMult
 from repro.models import (
-    Caches,
     decode_step,
-    decode_step_unified,
     decode_step_ws,
     init_caches,
     prefill,
-    unified_step_supported,
     ws_decode_supported,
 )
-from repro.models.unified import require_interpreter
 from repro.wstrace import spans
 from repro.wstrace.metrics import SchedulerMetrics
 
@@ -99,10 +94,6 @@ class ContinuousBatcher:
         attn_schedule: str = "ws",
         use_ws: bool = True,
         jit_ws: bool = False,
-        unified_step: bool = False,
-        step_deadline_s: Optional[float] = None,
-        watchdog_cooldown: int = 1,
-        fault_plan=None,
     ):
         self.params, self.cfg = params, cfg
         self.B, self.cap = slots, capacity
@@ -132,19 +123,6 @@ class ContinuousBatcher:
                 "for the dense decode step"
             )
         self.use_ws = bool(use_ws)
-        # Unified mode: ONE launch_ws_grid launch per engine step carries the
-        # decode tiles, at most one admitted prompt's prefill tiles, and (MoE)
-        # the expert tiles (models.unified, DESIGN.md §5).  admit() defers
-        # the prefill into the next step instead of running it standalone;
-        # the split-launch path below stays as the escape hatch and oracle.
-        # It runs interpreted only: on an accelerator it raises here.
-        if unified_step:
-            if not unified_step_supported(cfg):
-                raise ValueError(f"unified_step unsupported for config {cfg.name!r}")
-            require_interpreter()
-        self.unified = bool(unified_step)
-        self._pending = deque()          # (slot, Request) awaiting prefill
-        self._pending_slots: set = set()
         if self.use_ws and jit_ws:
             self._decode = jit_decode_step_ws(cfg, schedule=attn_schedule)
         elif self.use_ws:
@@ -165,18 +143,6 @@ class ContinuousBatcher:
         # admissions) — read it back via stats()
         self.metrics = SchedulerMetrics(slots=slots)
         spans.install_gc_spans()
-        # Watchdog (unified mode): a step whose logits come back non-finite
-        # is discarded and redone on the split path this very step; a step
-        # that blows `step_deadline_s` routes the next `watchdog_cooldown`
-        # steps through the split path.  `fault_plan` (a
-        # repro.chaos.EngineFaultPlan) injects poisoned logits / inflated
-        # latencies at chosen steps so both trips are drillable.
-        self.step_deadline_s = step_deadline_s
-        self.watchdog_cooldown = int(watchdog_cooldown)
-        self.fault_plan = fault_plan
-        self.degradations: List[dict] = []
-        self._step_idx = 0
-        self._degraded_until = -1
 
     # -- sampling --------------------------------------------------------------
     def _select(self, logits) -> np.ndarray:
@@ -210,23 +176,10 @@ class ContinuousBatcher:
         # generated token (admitting len >= capacity corrupts the splice)
         if not 0 < len(req.tokens) < self.cap:
             return False
-        free = [
-            i for i, r in enumerate(self.live)
-            if r is None and i not in self._pending_slots
-        ]
+        free = [i for i, r in enumerate(self.live) if r is None]
         if not free:
             return False
         slot = free[0]
-        if self.unified:
-            # defer the prefill into the next unified step — it rides the
-            # same launch as that step's decode tiles
-            self.live[slot] = req
-            self._pending.append((slot, req))
-            self._pending_slots.add(slot)
-            self.pos[slot] = 0
-            self.budget[slot] = req.max_new
-            self.metrics.record_admission()
-            return True
         with jax.profiler.TraceAnnotation(spans.ENGINE_ADMIT, rid=int(req.rid),
                                           slot=slot, prompt_len=len(req.tokens)):
             with jax.profiler.TraceAnnotation(spans.ADMIT_PREFILL):
@@ -250,183 +203,37 @@ class ContinuousBatcher:
         with jax.profiler.TraceAnnotation(spans.ENGINE_STEP,
                                           step=len(self.metrics.step_latency_s),
                                           live=self.n_live):
-            if self.unified:
-                return self._step_unified()
-            return self._step_split()
-
-    def _step_split(self) -> List[Request]:
-        n_live = self.n_live
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(spans.STEP_INPUTS):
-            tokens = np.zeros((self.B, 1), dtype=np.int32)
-            for i, r in enumerate(self.live):
-                if r is not None:
-                    tokens[i, 0] = r.out[-1]
-            # per-slot decode positions (heterogeneous sequence lengths)
-            tok, pos = jnp.asarray(tokens), jnp.asarray(self.pos)
-        with jax.profiler.TraceAnnotation(spans.STEP_DISPATCH):
-            logits, self.caches = self._decode(self.params, self.caches, tok, pos)
-        with jax.profiler.TraceAnnotation(spans.STEP_SYNC):
-            lg = np.asarray(logits)
-        with jax.profiler.TraceAnnotation(spans.STEP_SAMPLE):
-            nxt = self._select(lg)
-        self.metrics.record_step(time.perf_counter() - t0, n_live)
-        done = []
-        with jax.profiler.TraceAnnotation(spans.STEP_COMMIT):
-            for i, r in enumerate(self.live):
-                if r is None:
-                    continue
-                r.out.append(int(nxt[i]))
-                self.pos[i] += 1
-                self.budget[i] -= 1
-                if self.budget[i] <= 0 or self.pos[i] >= self.cap - 1:
-                    done.append(r)
-                    self.live[i] = None
-            if done:
-                self.metrics.record_completion(len(done))
-        return done
-
-    def _degrade(self, step_idx: int, kind: str, detail: str) -> None:
-        self.degradations.append(dict(step=step_idx, kind=kind, detail=detail))
-        self.metrics.record_degradation(kind)
-
-    def _step_unified(self) -> List[Request]:
-        """One engine step = ONE mixed-mode megakernel launch: all live
-        slots' decode tiles plus (at most) one pending admission's prefill
-        tiles, stage-gated in a single `launch_ws_grid` grid.
-
-        A per-step watchdog guards the launch: non-finite logits discard
-        the unified result and redo the step on the split path (standalone
-        prefill + per-step decode — graceful degradation, not a crash);
-        blowing ``step_deadline_s`` routes the following
-        ``watchdog_cooldown`` steps through the split path directly."""
-        fold = self._pending.popleft() if self._pending else None
-        n_live = self.n_live
-        t0 = time.perf_counter()
-        step_idx = self._step_idx
-        self._step_idx += 1
-        done = None
-        if step_idx >= self._degraded_until:
-            done = self._try_unified(fold, step_idx)
-        if done is None:
-            done = self._step_split_fallback(fold)
-        elapsed = time.perf_counter() - t0
-        observed = elapsed
-        if self.fault_plan is not None and self.fault_plan.slows(step_idx):
-            observed += self.fault_plan.added_latency_s
-        if (self.step_deadline_s is not None
-                and observed > self.step_deadline_s
-                and step_idx >= self._degraded_until):
-            self._degrade(step_idx, "deadline",
-                          f"step took {observed:.4f}s > "
-                          f"{self.step_deadline_s:.4f}s; next "
-                          f"{self.watchdog_cooldown} step(s) on split path")
-            self._degraded_until = step_idx + 1 + self.watchdog_cooldown
-        self.metrics.record_step(elapsed, n_live)
-        if done:
-            self.metrics.record_completion(len(done))
-        return done
-
-    def _try_unified(self, fold, step_idx: int) -> Optional[List[Request]]:
-        """The unified launch + bookkeeping; returns None (nothing
-        committed — caches untouched, no token appended) when the watchdog
-        rejects the launch's logits."""
-        tokens = np.zeros((self.B, 1), dtype=np.int32)
-        for i, r in enumerate(self.live):
-            if r is not None and r.out:
-                tokens[i, 0] = r.out[-1]
-        ptok = (
-            jnp.asarray(fold[1].tokens, jnp.int32)[None, :]
-            if fold is not None else None
-        )
-        logits, caches, rep = decode_step_unified(
-            self.params, self.cfg, self.caches, jnp.asarray(tokens), self.pos,
-            prefill_tokens=ptok,
-        )
-        lg = np.asarray(logits)  # syncs the device step
-        plg = np.asarray(rep.prefill_logits) if fold is not None else None
-        if self.fault_plan is not None and self.fault_plan.poisons(step_idx):
-            lg = np.full_like(lg, np.nan)
-        if not np.isfinite(lg).all() or (
-                plg is not None and not np.isfinite(plg).all()):
-            self._degrade(step_idx, "non-finite",
-                          "unified logits non-finite; redoing the step on "
-                          "the split path")
-            return None
-        self.caches = caches
-        done = []
-        nxt = self._select(lg)
-        folded_slot = -1
-        if fold is not None:
-            slot, req = fold
-            self._pending_slots.discard(slot)
-            folded_slot = slot
-            self._splice_slot(slot, Caches(kv=rep.prefill_kv))
-            first = int(self._select(plg)[0])
-            req.out.append(first)
-            self.pos[slot] = len(req.tokens)
-            self.budget[slot] = req.max_new - 1
-            if self.budget[slot] <= 0 or self.pos[slot] >= self.cap - 1:
-                done.append(req)
-                self.live[slot] = None
-        for i, r in enumerate(self.live):
-            # slots still awaiting their prefill fold (and the slot folded
-            # this step) produced no decode token this launch
-            if r is None or i in self._pending_slots or i == folded_slot:
-                continue
-            r.out.append(int(nxt[i]))
-            self.pos[i] += 1
-            self.budget[i] -= 1
-            if self.budget[i] <= 0 or self.pos[i] >= self.cap - 1:
-                done.append(r)
-                self.live[i] = None
-        return done
-
-    def _step_split_fallback(self, fold) -> List[Request]:
-        """Graceful degradation for one unified step: the same admission +
-        decode work done as split launches (standalone prefill, per-step
-        decode).  Greedy decode is deterministic, so the tokens this path
-        produces are exactly what the healthy unified launch would have
-        produced (PR 8's bitwise split/unified parity)."""
-        done = []
-        folded_slot = -1
-        if fold is not None:
-            slot, req = fold
-            self._pending_slots.discard(slot)
-            folded_slot = slot
-            batch = {"tokens": jnp.asarray(req.tokens, jnp.int32)[None, :]}
-            logits1, c1 = self._prefill(self.params, batch)
-            self._splice_slot(slot, c1)
-            first = int(self._select(np.asarray(logits1[:1]))[0])
-            req.out.append(first)
-            self.pos[slot] = len(req.tokens)
-            self.budget[slot] = req.max_new - 1
-            if self.budget[slot] <= 0 or self.pos[slot] >= self.cap - 1:
-                done.append(req)
-                self.live[slot] = None
-        decodable = [
-            i for i, r in enumerate(self.live)
-            if r is not None and r.out
-            and i not in self._pending_slots and i != folded_slot
-        ]
-        if decodable:
-            tokens = np.zeros((self.B, 1), dtype=np.int32)
-            for i in decodable:
-                tokens[i, 0] = self.live[i].out[-1]
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(tokens),
-                jnp.asarray(self.pos)
-            )
-            nxt = self._select(np.asarray(logits))
-            for i in decodable:
-                r = self.live[i]
-                r.out.append(int(nxt[i]))
-                self.pos[i] += 1
-                self.budget[i] -= 1
-                if self.budget[i] <= 0 or self.pos[i] >= self.cap - 1:
-                    done.append(r)
-                    self.live[i] = None
-        return done
+            n_live = self.n_live
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(spans.STEP_INPUTS):
+                tokens = np.zeros((self.B, 1), dtype=np.int32)
+                for i, r in enumerate(self.live):
+                    if r is not None:
+                        tokens[i, 0] = r.out[-1]
+                # per-slot decode positions (heterogeneous sequence lengths)
+                tok, pos = jnp.asarray(tokens), jnp.asarray(self.pos)
+            with jax.profiler.TraceAnnotation(spans.STEP_DISPATCH):
+                logits, self.caches = self._decode(self.params, self.caches,
+                                                   tok, pos)
+            with jax.profiler.TraceAnnotation(spans.STEP_SYNC):
+                lg = np.asarray(logits)
+            with jax.profiler.TraceAnnotation(spans.STEP_SAMPLE):
+                nxt = self._select(lg)
+            self.metrics.record_step(time.perf_counter() - t0, n_live)
+            done = []
+            with jax.profiler.TraceAnnotation(spans.STEP_COMMIT):
+                for i, r in enumerate(self.live):
+                    if r is None:
+                        continue
+                    r.out.append(int(nxt[i]))
+                    self.pos[i] += 1
+                    self.budget[i] -= 1
+                    if self.budget[i] <= 0 or self.pos[i] >= self.cap - 1:
+                        done.append(r)
+                        self.live[i] = None
+                if done:
+                    self.metrics.record_completion(len(done))
+            return done
 
     def stats(self) -> dict:
         """Serving metrics snapshot: per-step latency p50/p99 (ms), mean
@@ -541,11 +348,11 @@ class WorkStealingFrontend:
         return r
 
     def _crash(self, rep: int) -> None:
-        """Kill replica `rep`: its engine (slots, caches, pending folds) is
-        lost, its *queue* survives — queued-but-unadmitted requests stay
-        stealable by the living replicas, which is the paper's whole
-        point.  In-flight requests are re-admitted idempotently to
-        survivors keyed by rid + tokens-generated-so-far."""
+        """Kill replica `rep`: its engine (slots, caches) is lost, its
+        *queue* survives — queued-but-unadmitted requests stay stealable by
+        the living replicas, which is the paper's whole point.  In-flight
+        requests are re-admitted idempotently to survivors keyed by rid +
+        tokens-generated-so-far."""
         b = self.batchers[rep]
         self.dead.add(rep)
         self.counters["crashed"] += 1
@@ -553,8 +360,7 @@ class WorkStealingFrontend:
                      if i not in self.dead]
         inflight, seen = [], set()
         for r in list(b.live):
-            # unified-mode pending folds appear in b.live too, so this
-            # sweep covers deferred admissions; dedup by object identity
+            # one re-admission per request object, whatever slots hold it
             if r is not None and id(r) not in seen:
                 seen.add(id(r))
                 inflight.append(r)

@@ -25,9 +25,6 @@ class SchedulerMetrics:
         self.step_live: list[int] = []
         self.admitted = 0
         self.completed = 0
-        # watchdog trips (unified→split fallback events), keyed by kind
-        # ("non-finite", "deadline") — the graceful-degradation ledger
-        self.degradations: dict[str, int] = {}
 
     def record_step(self, latency_s: float, n_live: int) -> None:
         self.step_latency_s.append(float(latency_s))
@@ -38,9 +35,6 @@ class SchedulerMetrics:
 
     def record_completion(self, n: int = 1) -> None:
         self.completed += n
-
-    def record_degradation(self, kind: str) -> None:
-        self.degradations[kind] = self.degradations.get(kind, 0) + 1
 
     def snapshot(self) -> dict:
         """Reduce to a JSON-able dict: latency histogram summary (ms),
@@ -54,7 +48,6 @@ class SchedulerMetrics:
             "latency_ms": None,
             "slot_utilization": None,
             "live_mean": float(live.mean()) if live.size else 0.0,
-            "degradations": dict(self.degradations),
         }
         if lat.size:
             out["latency_ms"] = {

@@ -21,9 +21,8 @@ the lockstep *tile-slot round*, exported 1 round = 1 µs:
   advisory and collective-bytes counters.
 * **pid 3 "ws task families"** — one thread track per task family
   (resolved from EV_OP): the same extraction intervals re-grouped by
-  family, so a unified mixed-mode launch renders its decode / prefill /
-  expert / glue phases as parallel per-family timelines.  Slices only — no
-  extra counters or flows.
+  family.  A launch carries one family, so one launch's trace has one such
+  track.  Slices only — no extra counters or flows.
 
 Everything is derived from the plain-store event rings — the export adds
 zero cost to the traced run.

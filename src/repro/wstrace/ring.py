@@ -32,8 +32,7 @@ EV_VICTIM  owner program of the stolen queue (steals where the queue has a
            and for unowned queues (expert layouts with n_queues > P)
 EV_MULT    the task's multiplicity counter *after* this execution
 EV_OP      the claimed record's op id (``tasks.F_OP``) — identifies the task
-           family of the event, so a mixed-mode launch (unified engine step)
-           decodes into per-family timelines
+           family of the event (a launch carries one family)
 EV_RUN     slots claimed by the extraction this event belongs to — 1 for
            single-slot Take/Steal, the half-run length for amortized steals
            (``steal_run_cap > 1``), where one probe claims a contiguous run

@@ -134,8 +134,8 @@ class WSTrace:
         return hist
 
     def family_counts(self) -> dict:
-        """Extractions per task family (via EV_OP) — in a unified mixed-mode
-        launch this shows all families flowing through ONE ring stream."""
+        """Extractions per task family (via EV_OP).  A launch carries one
+        family, so one launch's trace has one entry."""
         out: dict = {}
         if self.n_events:
             ops, counts = np.unique(self.events[:, EV_OP], return_counts=True)

@@ -1,4 +1,5 @@
-"""Shared test configuration: hypothesis profiles + fault-drill fixtures.
+"""Shared test configuration: hypothesis profiles, fault-drill fixtures and
+the order in which xdist hands out slow files.
 
 * ``dev`` (default) — the tier-1 smoke depth: few examples so the full
   suite stays fast on a laptop and in the tier-1 CI job.
@@ -35,6 +36,8 @@ else:
     settings.register_profile("ci", max_examples=40, derandomize=True, **_COMMON)
     settings.load_profile("dev")
 
+import random  # noqa: E402
+
 import pytest  # noqa: E402
 
 try:
@@ -49,6 +52,13 @@ try:
     HAVE_CHAOS = True
 except ImportError:  # bare env without src on the path
     HAVE_CHAOS = False
+
+
+def rng_draws(seed):
+    """Seeded ``(draw_int, draw_bool)`` source for the deterministic slices
+    of the conformance suites (the hypothesis drivers draw the same way)."""
+    rng = random.Random(seed)
+    return (lambda lo, hi: rng.randint(lo, hi)), (lambda: rng.random() < 0.5)
 
 
 def full_rewind(state, res):
@@ -69,6 +79,28 @@ def drawn_rewind(state, res, draw_int, draw_bool, *, heads=None,
                            advisory_modes=advisory_modes)
     apply_rewind(state, spec)
     return spec
+
+
+# Under ``--dist loadfile`` xdist hands files out by test count, most first,
+# so a slow file with few cases would start late and set the suite's wall
+# time.  The files named here start first.
+SLOW_FILES = ("tests/test_mesh_conformance.py",)
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class SlowFilesFirst(LoadFileScheduling):
+        def _assign_work_unit(self, node):
+            for scope in reversed(SLOW_FILES):
+                if scope in self.workqueue:
+                    self.workqueue.move_to_end(scope, last=False)
+            super()._assign_work_unit(node)
+
+    return SlowFilesFirst(config, log)
 
 
 @pytest.fixture

@@ -18,19 +18,13 @@ from repro.serving import ContinuousBatcher
 ROOT = Path(__file__).resolve().parents[1]
 
 
-@pytest.fixture(scope="module")
-def smoke_llama():
-    from repro.models import init_params
-
-    cfg = get_config("llama3.2-3b", smoke=True)
-    return init_params(jax.random.PRNGKey(0), cfg), cfg
-
-
 def test_cpu_backend_interprets():
     assert interpret_mode() is (jax.default_backend() == "cpu")
 
 
-@pytest.mark.parametrize("arch", ["mamba2-2.7b", "gemma3-12b"])
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "gemma3-12b",
+                                  "deepseek-v2-236b", "zamba2-2.7b",
+                                  "whisper-base"])
 def test_ws_decode_refuses_uncovered_arch(arch):
     """An architecture decode_step_ws does not cover must ask for the dense
     step; asking for WS raises instead of turning dense."""
@@ -39,18 +33,6 @@ def test_ws_decode_refuses_uncovered_arch(arch):
         ContinuousBatcher(None, cfg, slots=2, capacity=16, use_ws=True)
     b = ContinuousBatcher(None, cfg, slots=2, capacity=16, use_ws=False)
     assert not b.use_ws
-
-
-def test_unified_step_raises_off_the_interpreter(smoke_llama, monkeypatch):
-    """On an accelerator backend the unified one-launch step raises rather
-    than running interpreted."""
-    import repro.models.unified as unified
-
-    params, cfg = smoke_llama
-    monkeypatch.setattr(unified, "interpret_mode", lambda: False)
-    with pytest.raises(NotImplementedError, match="interpreter only"):
-        ContinuousBatcher(params, cfg, slots=2, capacity=32,
-                          unified_step=True)
 
 
 def test_serve_main_serves_every_request_once(monkeypatch, tmp_path, capsys):

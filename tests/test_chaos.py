@@ -21,9 +21,8 @@ protocol, so the *only* acceptable outcomes are the WS-WMULT guarantees:
      under the deterministic adversarial simulator;
   4. serving chaos — replica crashes re-admit in-flight requests
      idempotently (no duplicate tokens, streams identical to an
-     uninterrupted run), transient admissions back off and give up
-     visibly, and the unified-step watchdog degrades to the split path
-     on poisoned logits / blown deadlines without changing any token;
+     uninterrupted run), and transient admissions back off and give up
+     visibly;
   5. checkpoint crash drill — a crash mid-publish can never tear
      ``latest_step`` (write-then-rename), and the async writer surfaces
      the error instead of swallowing it.
@@ -47,7 +46,6 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.chaos import (  # noqa: E402
-    EngineFaultPlan,
     FaultPlan,
     ReplicaCrashPlan,
     SafetyChecker,
@@ -69,11 +67,7 @@ from repro.pallas_ws import (  # noqa: E402
 )
 from repro.pallas_ws.kernel import default_rounds, run_ws_schedule  # noqa: E402
 from repro.pallas_ws.queues import copy_state  # noqa: E402
-from repro.serving.engine import (  # noqa: E402
-    ContinuousBatcher,
-    Request,
-    WorkStealingFrontend,
-)
+from repro.serving.engine import Request, WorkStealingFrontend  # noqa: E402
 
 P = 3  # programs: fewer than the expert count, so thieves roam
 
@@ -413,7 +407,7 @@ def test_host_faults_respect_weak_multiplicity(seed):
 
 
 # ---------------------------------------------------------------------------
-# serving chaos: crash re-admission, backoff give-up, watchdog fallback
+# serving chaos: crash re-admission, backoff give-up
 # ---------------------------------------------------------------------------
 
 
@@ -549,82 +543,6 @@ def test_permanent_rejection_bypasses_backoff():
     fe.run(max_iters=50)
     assert 0 in fe.rejected
     assert fe.counters["gave_up"] == 0, "over-capacity is permanent"
-
-
-# -- watchdog: unified -> split graceful degradation (real smoke model) ----
-
-
-@pytest.fixture(scope="module")
-def smoke_model():
-    from repro.configs import get_config
-    from repro.models import init_params
-
-    cfg = get_config("llama3.2-3b", smoke=True)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    return params, cfg
-
-
-def _drain(b, reqs, iters=24):
-    for r in reqs:
-        assert b.admit(r)
-    done = []
-    for _ in range(iters):
-        done += b.step()
-        if not b.n_live:
-            break
-    assert not b.n_live
-    return {r.rid: list(r.out) for r in done}
-
-
-def _watchdog_requests():
-    return [
-        Request(0, np.array([5, 6, 7, 8], np.int32), max_new=3),
-        Request(1, np.array([9, 8, 7], np.int32), max_new=3),
-    ]
-
-
-def test_watchdog_poisoned_logits_fall_back_bitwise(smoke_model):
-    """Poisoned (NaN) unified logits: the step is discarded and redone on
-    the split path the same step — greedy streams stay identical to the
-    fault-free unified run, and the degradations are recorded."""
-    params, cfg = smoke_model
-    streams = {}
-    for label, fp in (("clean", None),
-                      ("poisoned", EngineFaultPlan(poison_steps=(0, 2)))):
-        b = ContinuousBatcher(params, cfg, slots=2, capacity=32,
-                              unified_step=True, fault_plan=fp)
-        streams[label] = _drain(b, _watchdog_requests())
-        if label == "poisoned":
-            kinds = [d["kind"] for d in b.degradations]
-            assert kinds == ["non-finite", "non-finite"], b.degradations
-            assert b.stats()["degradations"] == {"non-finite": 2}
-        else:
-            assert b.degradations == []
-    assert streams["clean"] == streams["poisoned"]
-
-
-def test_watchdog_deadline_routes_cooldown_steps_split(smoke_model):
-    """A blown step deadline routes the next `watchdog_cooldown` steps
-    through the split path directly — same tokens, one recorded
-    degradation event."""
-    params, cfg = smoke_model
-    streams = {}
-    # the deadline sits far above honest interpret-mode step times (~1-2s)
-    # so only the injected 1e9 s latency can breach it
-    for label, kw in (
-        ("clean", {}),
-        ("slow", dict(step_deadline_s=120.0, watchdog_cooldown=2,
-                      fault_plan=EngineFaultPlan(slow_steps=(1,),
-                                                 added_latency_s=1e9))),
-    ):
-        b = ContinuousBatcher(params, cfg, slots=2, capacity=32,
-                              unified_step=True, **kw)
-        streams[label] = _drain(b, _watchdog_requests())
-        if label == "slow":
-            kinds = [d["kind"] for d in b.degradations]
-            assert kinds == ["deadline"], b.degradations
-            assert b.degradations[0]["step"] == 1
-    assert streams["clean"] == streams["slow"]
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,8 @@ Model-backed engine tests: the `greedy` flag actually selecting the
 sampler, prompt-capacity validation at the cap-1/cap/cap+1 boundary, the
 frontend honoring admit()'s verdict (rejections surfaced, never silently
 dropped), a deterministic seeded arrival trace through a 2-replica
-frontend, and unified-vs-split token-stream parity at the engine level.
+frontend, and the served construction (the compiled WS step behind the
+frontend) giving the dense step's token streams.
 
 Everything runs the llama smoke config (tiny f32 dense GQA) so the decode
 launches stay interpret-mode cheap.
@@ -82,13 +83,18 @@ def test_greedy_sampled_streams_diverge_in_generation():
 # satellite 2: admission capacity validation at the boundary
 
 
-@pytest.mark.parametrize("unified", [False, True])
-def test_admit_capacity_boundary(unified):
+@pytest.mark.parametrize("path", [
+    pytest.param({}, id="eager-ws"),
+    pytest.param({"jit_ws": True}, id="jit-ws"),
+    pytest.param({"use_ws": False}, id="dense"),
+])
+def test_admit_capacity_boundary(path):
     """cap-1 is the longest admissible prompt (the splice needs len rows
     plus one for the first generated token); len == cap used to corrupt
-    the cache splice, len == 0 to admit an empty prompt."""
+    the cache splice, len == 0 to admit an empty prompt.  Every decode
+    path of the batcher shares the check."""
     cap = 8
-    b = _batcher(capacity=cap, unified_step=unified)
+    b = _batcher(capacity=cap, **path)
     assert not b.admit(Request(1, np.arange(cap, dtype=np.int32)))      # == cap
     assert not b.admit(Request(2, np.arange(cap + 1, dtype=np.int32)))  # cap+1
     assert not b.admit(Request(3, np.zeros(0, np.int32)))               # empty
@@ -165,29 +171,38 @@ def test_seeded_trace_replay_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# tentpole acceptance at the engine level: unified == split token streams
+# the served construction gives the dense step's streams
 
 
-def test_engine_unified_matches_split_streams():
-    """The same seeded 2-request load through a unified-step batcher and a
-    split-launch (jitted oracle) batcher: identical greedy token streams.
-    The unified engine defers each admission's prefill into the next
-    step's single launch, so completion may land on a later iteration —
-    but per-slot token streams must be bit-identical."""
+@pytest.mark.parametrize("moe_ws", [
+    pytest.param(False, id="llama3.2-3b"),
+    pytest.param(True, id="kimi-k2-1t-a32b-moe-ws"),  # expert tiles too
+])
+def test_served_batcher_matches_dense_streams(moe_ws):
+    """The benchmark's construction — one replica of the frontend over a
+    ContinuousBatcher(jit_ws=True) — gives the same greedy token streams
+    as the same load on the dense decode step (use_ws=False)."""
+    if moe_ws:
+        cfg = get_config("kimi-k2-1t-a32b", smoke=True).replace(
+            moe_dispatch="ws")
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    else:
+        cfg, params = CFG, PARAMS
     prompts = [
         np.array([5, 6, 7, 8], np.int32),
         np.array([9, 8, 7], np.int32),
     ]
     streams = {}
-    for unified in (False, True):
+    for label, kw in (("served", {"jit_ws": True}),
+                      ("dense", {"use_ws": False})):
         fe = WorkStealingFrontend(
-            lambda: _batcher(capacity=32, unified_step=unified,
-                             jit_ws=not unified),
+            lambda: ContinuousBatcher(params, cfg, slots=2, capacity=32, **kw),
             n_replicas=1,
         )
         for rid, p in enumerate(prompts):
             fe.submit(0, Request(rid, p, max_new=3))
         completed = fe.run(max_iters=50)
         assert set(completed) == {0, 1}
-        streams[unified] = {rid: r.out for rid, r in completed.items()}
-    assert streams[True] == streams[False]
+        streams[label] = {rid: r.out for rid, r in completed.items()}
+    assert all(len(out) == 3 for out in streams["served"].values())
+    assert streams["served"] == streams["dense"]

@@ -189,12 +189,20 @@ def test_transformer_block_scan_runs_dropless_under_jit():
 # ---------------------------------------------------------------------------
 
 
-def test_jit_decode_step_ws_matches_eager():
+@pytest.mark.parametrize("arch,overrides", [
+    pytest.param("llama3.2-3b", {}, id="llama3.2-3b"),      # GQA, G = 2
+    pytest.param("minicpm-2b", {}, id="minicpm-2b"),        # MHA, G = 1
+    pytest.param("kimi-k2-1t-a32b", {"moe_dispatch": "ws"},  # expert tiles
+                 id="kimi-k2-1t-a32b-moe-ws"),
+])
+def test_jit_decode_step_ws_matches_eager(arch, overrides):
     from repro.models import decode_step, decode_step_ws, prefill
     from repro.models.transformer import init_params
     from repro.serving.engine import jit_decode_step_ws
 
-    cfg = get_config("llama3.2-3b", smoke=True)
+    cfg = get_config(arch, smoke=True)
+    if overrides:
+        cfg = cfg.replace(**overrides)
     params = init_params(jax.random.PRNGKey(0), cfg)
     batch = {"tokens": jnp.asarray(np.array([[5, 6, 7, 8], [9, 8, 7, 6]], np.int32))}
     _, caches = prefill(params, cfg, batch, capacity=32)
@@ -210,6 +218,24 @@ def test_jit_decode_step_ws_matches_eager():
     )
     l_d, _ = decode_step(params, cfg, caches, tok, pos)
     np.testing.assert_allclose(np.asarray(l_j), np.asarray(l_d), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(0, id="llama3.2-3b-dense"),
+    pytest.param(1, id="kimi-k2-1t-a32b-moe-ws"),
+])
+def test_served_step_lowering_is_fence_free(case):
+    """The compiled decode step the engine serves lowers with 0 RMW /
+    0 locks / 0 fences (the audit asserts the scan internally)."""
+    from benchmarks.zero_cost import SERVED_STEP_CASES, audit_served_step
+
+    exp, arch, _ = SERVED_STEP_CASES[case]
+    (row,) = audit_served_step(cases=(SERVED_STEP_CASES[case],))
+    assert row["experiment"] == exp
+    assert row["algorithm"] == f"served-step[{arch}]"
+    assert row["rmws_per_op"] == row["locks_per_op"] == 0
+    assert row["fences_per_op"] == 0
+    assert row["hlo_bytes"] > 0
 
 
 def test_traced_ragged_decode_matches_host_and_oracle():
